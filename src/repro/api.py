@@ -165,17 +165,20 @@ def _mesh_act_pspec(backend, B: int):
     return NamedSharding(mesh, partition.act_pspec(mesh, "replicated"))
 
 
-def _decode_act_pspec(backend, B: int):
-    """Layer-boundary residual anchor for the pipelined decode cells.
+def _serve_act_pspec(backend, B: int):
+    """Residual anchor for the serving cells (prefill, chunked prefill,
+    decode).
 
     The sharded matmul path leaves TP outputs model-sharded (reduce-scatter
-    + lazy gather, `core/backend.py`); this constraint tells GSPMD the
-    residual must be whole again only AT the layer boundary, so the
+    + lazy gather, `core/backend.py`); in decode this constraint tells GSPMD
+    the residual must be whole again only AT the layer boundary, so the
     all-gather lands next to the residual add — after the epilogue, where
     it overlaps the next layer's kernels — instead of wherever propagation
-    happens to cut it.  Unlike the train-cell ``_mesh_act_pspec`` it also
-    applies on pure-TP meshes (dp == 1); None off-mesh and on a 1x1 mesh,
-    preserving the unsharded cells bit-for-bit."""
+    happens to cut it.  Monolithic prefill also applies it to each
+    sublayer's output (``tfm._gather_for_prefill``).  Unlike the train-cell
+    ``_mesh_act_pspec`` it also applies on pure-TP meshes (dp == 1); None
+    off-mesh and on a 1x1 mesh, preserving the unsharded cells
+    bit-for-bit."""
     mesh = _backend_mesh(backend)
     if mesh is None:
         return None
@@ -207,7 +210,7 @@ def _prefill_cell(bank, batch, last, *, cfg: ModelConfig, backend,
     caches = _constrain_caches(caches, cfg, backend, B, cache_len)
     logits, caches, _ = tfm.forward(bank, cfg, batch, mode="prefill",
                                     caches=caches, execution=backend,
-                                    act_pspec=_mesh_act_pspec(backend, B))
+                                    act_pspec=_serve_act_pspec(backend, B))
     caches = _constrain_caches(caches, cfg, backend, B, cache_len)
     return logits[jnp.arange(B), last], caches
 
@@ -241,7 +244,7 @@ def _prefill_chunk_cells(donate: bool):
         logits, caches, _ = tfm.forward(
             bank, cfg, {"tokens": tokens}, mode="prefill_chunk",
             caches=caches, pos=q_offset, execution=backend,
-            act_pspec=_decode_act_pspec(backend, B))
+            act_pspec=_serve_act_pspec(backend, B))
         return logits[jnp.arange(B), last], caches
 
     return prefill_chunk_cell
@@ -262,7 +265,7 @@ def _decode_cells(donate: bool):
         logits, caches, _ = tfm.forward(
             bank, cfg, {"tokens": tokens}, mode="decode", caches=caches,
             pos=pos, execution=backend,
-            act_pspec=_decode_act_pspec(backend, tokens.shape[0]))
+            act_pspec=_serve_act_pspec(backend, tokens.shape[0]))
         return logits[:, 0, :], caches
 
     @functools.partial(jax.jit,
@@ -276,7 +279,7 @@ def _decode_cells(donate: bool):
         logits, caches, _ = tfm.forward(
             bank, cfg, {"tokens": tokens}, mode="decode", caches=caches,
             pos=pos, execution=backend,
-            act_pspec=_decode_act_pspec(backend, tokens.shape[0]))
+            act_pspec=_serve_act_pspec(backend, tokens.shape[0]))
         logits = _mask_padded(logits[:, 0, :].astype(jnp.float32),
                               cfg.vocab_size)
         if greedy:
@@ -342,17 +345,24 @@ class Program:
         if mesh is not None and bk_mesh is None:
             bk = dataclasses.replace(bk, mesh=mesh)
         mesh = getattr(bk, "mesh", None)
-        bank = _prepare_cell(params, cfg=cfg, photonic=bk.is_photonic)
         dropped = 0
         if mesh is not None:
+            # place the fp params on the mesh first, so the prepare cell
+            # quantizes each shard where it lives: no device ever holds the
+            # whole unsharded bank
             report = partition.PartitionReport(dropped=[])
-            sh = partition.bank_shardings(bank, tfm.model_specs(cfg), mesh,
-                                          cfg.fsdp, report)
-            bank = jax.device_put(bank, sh)
+            specs = tfm.model_specs(cfg)
+            params = jax.device_put(params, partition.param_shardings(
+                params, specs, mesh, cfg.fsdp, report))
+            bank = _prepare_cell(params, cfg=cfg, photonic=bk.is_photonic)
+            bank = jax.device_put(bank, partition.bank_shardings(
+                bank, specs, mesh, cfg.fsdp))
             dropped = len(report.dropped)
             if report.dropped:
                 warnings.warn(partition.dropped_summary(report),
                               stacklevel=2)
+        else:
+            bank = _prepare_cell(params, cfg=cfg, photonic=bk.is_photonic)
         # bank/partition accounting as registry gauges (last Program built
         # wins — builds are one-time events, not hot-path)
         reg = metrics_lib.default_registry()

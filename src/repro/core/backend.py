@@ -65,7 +65,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import obu
@@ -76,6 +75,7 @@ from repro.core.prepared import (PreparedTensor, quantize_weight,
                                  quantize_weight_t)
 from repro.kernels import flash_attention as _fa
 from repro.kernels import ops
+from repro.kernels.platform import default_interpret
 from repro.kernels.photonic_mvm import tile_plan
 
 EXECUTIONS = ("xla", "photonic")
@@ -168,16 +168,18 @@ def _apply_activation(y, activation):
 
 def _epilogue_unfused(y, bias, block_perm, block, activation):
     """The split blend epilogue: a second Pallas pass for blocked shuffles
-    (`kernels/blend.py`), plain jnp for bias/activation-only epilogues —
-    exactly what the model layers ran before the fusion existed."""
+    (`kernels/blend.py`), plain jnp for bias/activation-only epilogues.
+    Either way it runs in f32 on the stored MVM output and rounds once to
+    its dtype, as the fused kernel's ``_finalize`` does."""
     if block_perm is not None:
         b = (jnp.zeros((y.shape[-1],), y.dtype) if bias is None
              else bias.astype(y.dtype))
         return ops.blend_shuffle(y, b, block_perm, block=block,
                                  activation=activation or "none")
+    out = y.astype(jnp.float32)
     if bias is not None:
-        y = y + bias.astype(y.dtype)
-    return _apply_activation(y, activation)
+        out = out + bias.astype(jnp.float32)
+    return _apply_activation(out, activation).astype(y.dtype)
 
 
 def _epilogue_xla(y, bias, block_perm, block, activation):
@@ -312,7 +314,7 @@ class Backend:
         B, Sq, H, _ = q.shape
         L, hd_v = k.shape[1], v.shape[-1]
         if self.use_flash(Sq):
-            bq, bk_ = _fa.default_blocks(Sq, L, _fa.default_interpret())
+            bq, bk_ = _fa.default_blocks(Sq, L, default_interpret())
             _metrics.record_kernel_call("flash_attn", bq, bk_, hd_v)
             with jax.named_scope(f"photonic.flash_attn.{bq}x{bk_}"):
                 o = ops.flash_attention(q, k, v, causal=causal,
@@ -534,9 +536,12 @@ class Backend:
 
             def kernel(wql, wssl, n_cols, epilogue):
                 """One per-shard Pallas call on ``n_cols`` output columns;
-                ``epilogue=False`` leaves the raw (partial) MVM for the
-                reduction collective to finish."""
+                ``epilogue=False`` leaves the raw (partial) MVM, in f32, for
+                the reduction collective to finish: partials rounded to a
+                bf16 activation dtype before the sum would lose what the
+                single-device kernel's f32 accumulator keeps."""
                 bm, bk, bn = plan(Ml, Kl, n_cols)
+                out_dtype = None if epilogue else jnp.float32
                 if fused:
                     return ops.photonic_matmul_fused(
                         xl, wql, wssl, x_scale=xsl, transpose=transpose,
@@ -544,10 +549,12 @@ class Backend:
                         block_perm=block_perm if epilogue else None,
                         block=block,
                         activation=(activation or "none") if epilogue
-                        else "none", bm=bm, bk=bk, bn=bn)
+                        else "none", bm=bm, bk=bk, bn=bn,
+                        out_dtype=out_dtype)
                 mm = (ops.photonic_matmul_prepared_t if transpose
                       else ops.photonic_matmul_prepared)
-                y = mm(xl, wql, wssl, bm=bm, bk=bk, bn=bn, x_scale=xsl)
+                y = mm(xl, wql, wssl, bm=bm, bk=bk, bn=bn, x_scale=xsl,
+                       out_dtype=out_dtype)
                 if epilogue:
                     y = _epilogue_unfused(y, bl, block_perm, block,
                                           activation)
@@ -559,7 +566,8 @@ class Backend:
                                          scatter_dimension=y.ndim - 1,
                                          tiled=True)
                 # slice-local epilogue: bl is already this shard's slice
-                return _epilogue_unfused(y, bl, None, 0, activation)
+                return _epilogue_unfused(y.astype(xl.dtype), bl, None, 0,
+                                         activation)
             if rule == "ring":
                 me = jax.lax.axis_index("model")
                 ring = [(i, (i + 1) % tp) for i in range(tp)]
@@ -580,19 +588,21 @@ class Backend:
                 for s in range(1, tp):
                     acc = jax.lax.ppermute(acc, "model", perm=ring)
                     acc = acc + part((me + tp - 1 - s) % tp)
-                return _epilogue_unfused(acc, bl, None, 0, activation)
+                return _epilogue_unfused(acc.astype(xl.dtype), bl, None, 0,
+                                         activation)
             if rule == "psum":
                 y = kernel(wl, wsl, N, epilogue=False)
                 y = jax.lax.psum(y, "model")
-                return _epilogue_unfused(y, bl, block_perm, block,
-                                         activation)
+                return _epilogue_unfused(y.astype(xl.dtype), bl, block_perm,
+                                         block, activation)
             # column / replicated: the kernel's own fused epilogue
             Nl = wl.shape[-2] if transpose else wl.shape[-1]
             return kernel(wl, wsl, Nl, epilogue=True)
 
         with jax.named_scope(f"photonic.sharded.{rule}"):
-            return shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                             out_specs=out_spec, check_rep=False)(*operands)
+            return jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                                 out_specs=out_spec,
+                                 check_vma=False)(*operands)
 
     def reuse_dot(self, x_stack, w):
         """T independent activation streams through ONE weight: x_stack
@@ -670,11 +680,11 @@ class Backend:
                                    x_stack.shape[-1],
                                    N // tp if col_tp else N))
         with jax.named_scope("photonic.sharded_reuse"):
-            return shard_map(
+            return jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(*mid, None), P(None, nspec), P(nspec)),
                 out_specs=P(*mid, nspec),
-                check_rep=False)(x_stack, wq, wscale)
+                check_vma=False)(x_stack, wq, wscale)
 
     # -------------------------------------------------------------- shuffle
     def shuffle(self, h, perm, block_perm=None, block: int = 0):
@@ -694,11 +704,11 @@ class Backend:
                 row_ok = dp > 1 and h.ndim >= 2 and h.shape[0] % dp == 0
                 bspec = _data_spec_entry(d_axes) if row_ok else None
                 hs = P(bspec, *(None,) * (h.ndim - 1))
-                return shard_map(
+                return jax.shard_map(
                     lambda hl, bl: ops.blend_shuffle(
                         hl, bl, block_perm, block=block, activation="none"),
                     mesh=mesh, in_specs=(hs, P(None)), out_specs=hs,
-                    check_rep=False)(h, bias)
+                    check_vma=False)(h, bias)
             with jax.named_scope("photonic.blend_shuffle"):
                 return ops.blend_shuffle(h, bias, block_perm, block=block,
                                          activation="none")

@@ -20,9 +20,12 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import default_interpret
+
 
 def _kernel(perm_ref, x_ref, b_ref, o_ref, *, activation: str):
-    y = x_ref[...] + b_ref[...]
+    # f32 epilogue: the v5e VPU has no bf16 arithmetic
+    y = x_ref[...].astype(jnp.float32) + b_ref[...].astype(jnp.float32)
     if activation == "relu":
         y = jnp.maximum(y, 0.0)
     elif activation == "silu":
@@ -31,14 +34,17 @@ def _kernel(perm_ref, x_ref, b_ref, o_ref, *, activation: str):
 
 
 def blend_shuffle(x, bias, block_perm, *, block=128, bm=128,
-                  activation="relu", interpret=True):
+                  activation="relu", interpret=None):
     """y[:, j*block:(j+1)*block] = act(x[:, perm[j]*block:...] + bias[...]).
 
     x: (M, C) with C == len(block_perm) * block; bias: (C,) added *after*
     the shuffle (indexed by output position).  ``block_perm`` arrives via
     TPU scalar prefetch so the input BlockSpec's index map can read it —
     the shuffle is realized purely as grid index remapping.
+    ``interpret=None`` resolves from the platform.
     """
+    if interpret is None:
+        interpret = default_interpret()
     M, C = x.shape
     if block <= 0 or C % block != 0:
         # a ragged channel axis would silently drop the C % block tail

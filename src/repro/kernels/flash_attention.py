@@ -36,13 +36,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import default_interpret
+
 NEG_INF = -1e30
-
-
-def default_interpret() -> bool:
-    """The platform check every Pallas kernel in this repo resolves against
-    (``ops._interpret`` delegates here): interpret off only on real TPUs."""
-    return jax.default_backend() != "tpu"
 
 
 def default_blocks(Sq: int, L: int, interpret: bool) -> tuple[int, int]:

@@ -1,8 +1,9 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels execute with ``interpret=True``; on a
-real TPU backend they lower natively.  All shape plumbing (quantization,
-padding, head flattening) lives here so callers stay tensor-shaped.
+Every kernel resolves ``interpret`` from the platform: off the TPU the
+kernels run in the Pallas interpreter, on a TPU they lower natively.  All
+shape plumbing (quantization, padding, head flattening) lives here so
+callers stay tensor-shaped.
 
 Three families of matmul entry points:
 
@@ -35,10 +36,6 @@ from repro.kernels import flash_attention as _fa
 from repro.kernels import photonic_mvm as _pm
 from repro.kernels import ssd as _ssd
 from repro.kernels.photonic_mvm import round_up, tile_plan  # noqa: F401
-
-
-def _interpret() -> bool:
-    return _fa.default_interpret()
 
 
 # =========================================================================
@@ -88,31 +85,30 @@ def _quantize_a8(x, x_scale):
 
 
 def photonic_matmul_prepared(x, wq, wscale, *, bm=128, bk=128, bn=128,
-                             qmax=127.0, x_scale=None):
+                             qmax=127.0, x_scale=None, out_dtype=None):
     """Offset-decomposed MVM against an already-programmed bank.
 
     wq: int8 (k, n) per-output-channel quantized; wscale: f32 (n,).  Only
     the activations are quantized here — the weight-side work (normalize,
-    round, scale derivation) happened once at ``Program.build`` time."""
+    round, scale derivation) happened once at ``Program.build`` time.
+    ``out_dtype`` defaults to x's dtype."""
     xq, xscale = _quantize_a8(x, x_scale)
     lead = x.shape[:-1]
     x2 = xq.reshape(-1, x.shape[-1])
     y = _pm.photonic_mvm(x2, wq, xscale, wscale.reshape(-1),
-                         bm=bm, bk=bk, bn=bn, qmax=qmax,
-                         interpret=_interpret())
-    return y.reshape(*lead, wq.shape[1]).astype(x.dtype)
+                         bm=bm, bk=bk, bn=bn, qmax=qmax)
+    return y.reshape(*lead, wq.shape[1]).astype(out_dtype or x.dtype)
 
 
 def photonic_matmul_prepared_t(x, wq, wscale, *, bm=128, bk=128, bn=128,
-                               qmax=127.0, x_scale=None):
+                               qmax=127.0, x_scale=None, out_dtype=None):
     """Prepared ``x @ w.T``: wq int8 (n, k) per-ROW quantized; wscale (n,)."""
     xq, xscale = _quantize_a8(x, x_scale)
     lead = x.shape[:-1]
     x2 = xq.reshape(-1, x.shape[-1])
     y = _pm.photonic_mvm_t(x2, wq, xscale, wscale,
-                           bm=bm, bk=bk, bn=bn, qmax=qmax,
-                           interpret=_interpret())
-    return y.reshape(*lead, wq.shape[0]).astype(x.dtype)
+                           bm=bm, bk=bk, bn=bn, qmax=qmax)
+    return y.reshape(*lead, wq.shape[0]).astype(out_dtype or x.dtype)
 
 
 def reuse_resident_matmul_prepared(x_stack, wq, wscale, *, bm=128, bn=128,
@@ -128,8 +124,7 @@ def reuse_resident_matmul_prepared(x_stack, wq, wscale, *, bm=128, bn=128,
     bm_eff = min(bm, round_up(x2.shape[1], 8))
     y = _pm.photonic_mvm_resident(xq, wq, xscale.reshape(T),
                                   wscale.reshape(-1),
-                                  bm=bm_eff, bn=bn,
-                                  qmax=qmax, interpret=_interpret())
+                                  bm=bm_eff, bn=bn, qmax=qmax)
     return y.reshape(T, *lead, wq.shape[1]).astype(x_stack.dtype)
 
 
@@ -138,7 +133,8 @@ def reuse_resident_matmul_prepared(x_stack, wq, wscale, *, bm=128, bn=128,
 # =========================================================================
 def photonic_matmul_fused(x, wq, wscale, *, transpose=False, bias=None,
                           block_perm=None, block=0, activation="none",
-                          bm=128, bk=128, bn=128, qmax=127.0, x_scale=None):
+                          bm=128, bk=128, bn=128, qmax=127.0, x_scale=None,
+                          out_dtype=None):
     """One-``pallas_call`` serving matmul against a prepared bank.
 
     x: fp (..., k); wq/wscale: a prepared orientation — (k, n)/per-column,
@@ -150,7 +146,8 @@ def photonic_matmul_fused(x, wq, wscale, *, transpose=False, bias=None,
     which XLA contracts into the rescale fma (<= 1 ulp; see
     ``photonic_mvm._kernel_fused``).  ``x_scale`` overrides the A8 scale
     (the shard_map'd backend passes the global activation's scale so a
-    partitioned matmul's shards all quantize on the single-device grid)."""
+    partitioned matmul's shards all quantize on the single-device grid).
+    ``out_dtype`` defaults to x's dtype."""
     xscale = a8_scale(x) if x_scale is None else x_scale
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
@@ -160,8 +157,7 @@ def photonic_matmul_fused(x, wq, wscale, *, transpose=False, bias=None,
     y = _pm.photonic_mvm_fused(
         x2, wq, xscale, wscale.reshape(-1), bias=bias, bm=bm, bk=bk, bn=bn,
         qmax=qmax, transpose=transpose, activation=activation,
-        block_perm=perm, block=block, interpret=_interpret(),
-        out_dtype=x.dtype)
+        block_perm=perm, block=block, out_dtype=out_dtype or x.dtype)
     return y.reshape(*lead, n_out)
 
 
@@ -191,8 +187,7 @@ def blend_shuffle(x, bias, block_perm, *, block=128, activation="relu"):
     x2 = x.reshape(-1, x.shape[-1])
     y = _blend.blend_shuffle(x2, bias, block_perm, block=block,
                              bm=min(128, round_up(x2.shape[0], 8)),
-                             activation=activation,
-                             interpret=_interpret())
+                             activation=activation)
     return y.reshape(*lead, x.shape[-1])
 
 
@@ -211,9 +206,9 @@ def flash_attention(q, k, v, *, causal=True, q_offset=None, bq=None,
     kf = k.transpose(0, 2, 1, 3).reshape(B * KV, L, hd)
     vf = v.transpose(0, 2, 1, 3).reshape(B * KV, L, hdv)
     o = _fa.flash_attention(qf, kf, vf, causal=causal, q_offset=q_offset,
-                            bq=bq, bk=bk, interpret=_interpret())
+                            bq=bq, bk=bk)
     return o.reshape(B, H, Sq, hdv).transpose(0, 2, 1, 3)
 
 
 def ssd_chunk(x, dA, B, C):
-    return _ssd.ssd_chunk(x, dA, B, C, interpret=_interpret())
+    return _ssd.ssd_chunk(x, dA, B, C)

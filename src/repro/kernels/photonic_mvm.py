@@ -84,6 +84,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import default_interpret
+
 
 def _kernel(xq_ref, wq_ref, xs_ref, ws_ref, o_ref, acc_ref, xsum_ref, *,
             nk: int, qmax: float):
@@ -144,20 +146,25 @@ def _kernel_t(xq_ref, wq_ref, xs_ref, ws_ref, o_ref, acc_ref, xsum_ref, *,
 def _kernel_resident(xq_ref, wq_ref, xs_ref, ws_ref, o_ref, *, qmax: float):
     """Reuse-resident step: the full (K, bn) weight tile is already in VMEM
     (its index map ignores the streaming grid dims) — each step only streams
-    one activation row-block through it."""
+    one activation row-block through it.  ``xs_ref`` is the whole (T,)
+    per-step scale vector in SMEM, read at this step's reuse index."""
     xf = xq_ref[0].astype(jnp.float32)                   # (bm, K)
     w_prime = wq_ref[...].astype(jnp.float32) / (2.0 * qmax) + 0.5
     y = jnp.dot(xf, w_prime, preferred_element_type=jnp.float32)
     y = 2.0 * (y - 0.5 * jnp.sum(xf, axis=1, keepdims=True))
-    o_ref[0] = (y * xs_ref[0, 0] * ws_ref[...]).astype(o_ref.dtype)
+    xs = xs_ref[pl.program_id(1)]
+    o_ref[0] = (y * xs * ws_ref[...]).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bk", "bn", "qmax",
                                              "interpret", "out_dtype"))
 def photonic_mvm(xq, wq, x_scale, w_scale, *, bm=128, bk=128, bn=128,
-                 qmax=127.0, interpret=True, out_dtype=jnp.float32):
+                 qmax=127.0, interpret=None, out_dtype=jnp.float32):
     """xq: (M, K) int8; wq: (K, N) int8 (symmetric, per-column scale);
-    x_scale: scalar; w_scale: (N,).  Returns (M, N) ``out_dtype``."""
+    x_scale: scalar; w_scale: (N,).  Returns (M, N) ``out_dtype``.
+    ``interpret=None`` resolves from the platform (off only on a TPU)."""
+    if interpret is None:
+        interpret = default_interpret()
     M, K = xq.shape
     K2, N = wq.shape
     assert K == K2
@@ -189,7 +196,7 @@ def photonic_mvm(xq, wq, x_scale, w_scale, *, bm=128, bk=128, bn=128,
 @functools.partial(jax.jit, static_argnames=("bm", "bk", "bn", "qmax",
                                              "interpret", "out_dtype"))
 def photonic_mvm_t(xq, wq, x_scale, w_scale, *, bm=128, bk=128, bn=128,
-                   qmax=127.0, interpret=True, out_dtype=jnp.float32):
+                   qmax=127.0, interpret=None, out_dtype=jnp.float32):
     """``xq @ wq.T`` for xq: (M, K) int8 and wq: (N, K) int8 (symmetric,
     per-ROW scale — the output channel of the transposed use); x_scale:
     scalar; w_scale: (N,).  Returns (M, N).
@@ -198,6 +205,8 @@ def photonic_mvm_t(xq, wq, x_scale, w_scale, *, bm=128, bk=128, bn=128,
     BlockSpec walks (N, K) tiles and ``_kernel_t`` swaps each (bn, bk) tile
     in-register — light entering the crossbar on the orthogonal port, never
     a materialized ``w.T``."""
+    if interpret is None:
+        interpret = default_interpret()
     M, K = xq.shape
     N, K2 = wq.shape
     assert K == K2
@@ -229,7 +238,7 @@ def photonic_mvm_t(xq, wq, x_scale, w_scale, *, bm=128, bk=128, bn=128,
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "qmax",
                                              "interpret", "out_dtype"))
 def photonic_mvm_resident(xq, wq, x_scale, w_scale, *, bm=128, bn=128,
-                          qmax=127.0, interpret=True, out_dtype=jnp.float32):
+                          qmax=127.0, interpret=None, out_dtype=jnp.float32):
     """Reuse-resident MVM: xq: (T, M, K) int8 — T reuse steps' activations
     streamed through ONE programmed weight; wq: (K, N) int8; x_scale: (T,)
     per-step A8 scales; w_scale: (N,).  Returns (T, M, N).
@@ -242,6 +251,8 @@ def photonic_mvm_resident(xq, wq, x_scale, w_scale, *, bm=128, bn=128,
     re-fetch.  The reduction depth K must fit one VMEM tile (no K grid dim),
     which holds for every d_model/d_ff in the paper models at TPU VMEM
     sizes; the offset row is recomputed per row-block (rank-1, free)."""
+    if interpret is None:
+        interpret = default_interpret()
     T, M, K = xq.shape
     K2, N = wq.shape
     assert K == K2
@@ -259,13 +270,15 @@ def photonic_mvm_resident(xq, wq, x_scale, w_scale, *, bm=128, bn=128,
             # weight index map ignores (t, i): programmed once, reused T*M/bm
             # times — write-once / reuse-T-times in BlockSpec form.
             pl.BlockSpec((Kp, bn), lambda j, t, i: (0, j)),
-            pl.BlockSpec((1, 1), lambda j, t, i: (t, 0)),
+            # a (1, 1) block of a (T, 1) array breaks the (8, 128) tiling
+            # rule: the T scalars ride whole in SMEM instead
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, bn), lambda j, t, i: (0, j)),
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda j, t, i: (t, i, j)),
         out_shape=jax.ShapeDtypeStruct((T, Mp, Np), out_dtype),
         interpret=interpret,
-    )(xq_p, wq_p, jnp.reshape(x_scale, (T, 1)).astype(jnp.float32),
+    )(xq_p, wq_p, jnp.reshape(x_scale, (T,)).astype(jnp.float32),
       ws_p.astype(jnp.float32))
     return out[:, :M, :N]
 
@@ -375,15 +388,13 @@ def _kernel_fused(oidx_ref, x_ref, wq_ref, xs_ref, ws_ref, *rest, nk: int,
         acc_ref[...] = jnp.zeros_like(acc_ref)
         xsum_ref[...] = jnp.zeros_like(xsum_ref)
 
-    # in-kernel A8: divide/round in the INPUT dtype, exactly like
-    # quantize_symmetric (bf16 activations round on the bf16 grid; the f32
-    # scale is the exact up-cast of the input-dtype scale, so the down-cast
-    # recovers it losslessly) — keeps fused == split bit-identical for
-    # every activation dtype, not just f32
-    scale = xs_ref[0, 0]
+    # in-kernel A8: divide and round in f32 (the v5e VPU has no bf16
+    # arithmetic), the quotient rounded to the activation dtype in between:
+    # the grid quantize_symmetric's x / scale lands on in that dtype, so
+    # fused == split stays bit-identical for every activation dtype
     x_in = x_ref[...]
-    xq = jnp.clip(jnp.round(x_in / scale.astype(x_in.dtype)),
-                  -qmax - 1.0, qmax).astype(jnp.float32)
+    q = (x_in.astype(jnp.float32) / xs_ref[0, 0]).astype(x_in.dtype)
+    xq = jnp.clip(jnp.round(q.astype(jnp.float32)), -qmax - 1.0, qmax)
     w = wq_ref[...].astype(jnp.float32)
     if transpose_w:
         w = w.T                                  # OBU port swap, in-register
@@ -395,7 +406,9 @@ def _kernel_fused(oidx_ref, x_ref, wq_ref, xs_ref, ws_ref, *rest, nk: int,
     def _finalize():
         y = 2.0 * (acc_ref[...] - 0.5 * xsum_ref[...])
         out_scale = xs_ref[0, 0] * ws_ref[...]
-        y = (y * out_scale).astype(o_ref.dtype)
+        # round to the output dtype (what the split path stores), then run
+        # the epilogue in f32 like the blend kernel: no bf16 VPU arithmetic
+        y = (y * out_scale).astype(o_ref.dtype).astype(jnp.float32)
         if has_bias:
             # the TIA rescale product feeds this add unrounded — XLA
             # contracts the pair into an fma (even across an
@@ -403,7 +416,7 @@ def _kernel_fused(oidx_ref, x_ref, wq_ref, xs_ref, ws_ref, *rest, nk: int,
             # the split path's store-then-add (and is the more accurate of
             # the two).  The bias-free epilogues (activation / blocked
             # shuffle — all the model path uses) stay bit-identical.
-            y = y + b_ref[...]
+            y = y + b_ref[...].astype(jnp.float32)
         o_ref[...] = _act(y, activation).astype(o_ref.dtype)
 
 
@@ -432,7 +445,7 @@ def _out_block_index(block_perm, block: int, N: int, bn: int) -> np.ndarray:
 def photonic_mvm_fused(x, wq, x_scale, w_scale, *, bias=None, bm=128, bk=128,
                        bn=128, qmax=127.0, transpose=False,
                        activation="none", block_perm=None, block=0,
-                       interpret=True, out_dtype=jnp.float32):
+                       interpret=None, out_dtype=jnp.float32):
     """The decode-path megakernel: one ``pallas_call`` for
     quantize -> offset-decomposed MVM -> bias -> activation -> blocked
     output shuffle.
@@ -446,6 +459,8 @@ def photonic_mvm_fused(x, wq, x_scale, w_scale, *, bias=None, bm=128, bk=128,
     ``block_perm[q]``, realized purely by the output BlockSpec's
     scalar-prefetched index map.  Returns (M, N) ``out_dtype``.
     """
+    if interpret is None:
+        interpret = default_interpret()
     M, K = x.shape
     if transpose:
         N, K2 = wq.shape

@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.platform import default_interpret
+
 NEG_INF = -1e30
 
 
@@ -48,14 +50,17 @@ def _kernel(x_ref, dA_ref, b_ref, c_ref, y_ref, st_ref, *, L: int):
     st_ref[...] = st.astype(st_ref.dtype)        # (N, P)
 
 
-def ssd_chunk(x, dA, B, C, *, interpret=True):
+def ssd_chunk(x, dA, B, C, *, interpret=None):
     """Intra-chunk SSD.
 
     x:  (b, nc, L, H, P)  dt-folded inputs
     dA: (b, nc, H, L)     per-step log decay (dt * A)
     B, C: (b, nc, L, H, N)  already head-broadcast
     Returns y_diag (b, nc, L, H, P) fp32 and states (b, nc, H, N, P) fp32.
+    ``interpret=None`` resolves from the platform.
     """
+    if interpret is None:
+        interpret = default_interpret()
     b, nc, L, H, P = x.shape
     N = B.shape[-1]
     grid = (b * nc, H)

@@ -10,6 +10,17 @@ from __future__ import annotations
 import numpy as np
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape: tuple, axes: tuple, devices):
+    """``jax.make_mesh`` with every axis Auto: GSPMD propagates shardings
+    from the params and caches (the partition rules), as the model code and
+    the ``shard_map``'d kernels expect.  ``make_mesh`` defaults to Explicit
+    axes, which make every unannotated gather and sharding constraint an
+    error."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -22,12 +33,12 @@ def make_production_mesh(*, multi_pod: bool = False):
         raise RuntimeError(
             f"mesh {shape} needs {n} devices, have {len(devices)} — "
             f"run via launch/dryrun.py which forces host platform devices")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return _auto_mesh(shape, axes, devices[:n])
 
 
 def make_mesh(shape: tuple, axes: tuple):
     n = int(np.prod(shape))
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:n])
+    return _auto_mesh(shape, axes, jax.devices()[:n])
 
 
 def parse_mesh(spec):
@@ -61,10 +72,8 @@ def make_mesh_auto(*, max_model: int = 4, devices=None):
     model = 1
     while model * 2 <= max_model and n % (model * 2) == 0:
         model *= 2
-    return jax.make_mesh((n // model, model), ("data", "model"),
-                         devices=devices)
+    return _auto_mesh((n // model, model), ("data", "model"), devices)
 
 
 def single_device_mesh():
-    return jax.make_mesh((1, 1), ("data", "model"),
-                         devices=jax.devices()[:1])
+    return _auto_mesh((1, 1), ("data", "model"), jax.devices()[:1])

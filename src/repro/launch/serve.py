@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from repro.api import Program
 from repro.configs import get_arch, smoke_variant
 from repro.launch import mesh as mesh_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as tfm
 from repro.obs import metrics as metrics_lib
 from repro.obs.serving import ServingObs
@@ -122,6 +123,9 @@ def main(argv=None):
                     help="write the metrics JSON snapshot "
                          "(benchmarks/metrics_schema.json shape) here")
     args = ap.parse_args(argv)
+    enable_compile_cache()
+    dev = jax.devices()[0]
+    where = f"on {dev.platform} {dev.device_kind}"
     cfg = smoke_variant(args.arch) if args.smoke else get_arch(
         args.arch, reuse=args.reuse)
     mesh = None
@@ -239,7 +243,7 @@ def main(argv=None):
         dt = time.time() - t0
         n_new = args.capacity * args.new_tokens
         print(f"[serve/engine] {cfg.name}: {n_new} tokens in {dt:.2f}s "
-              f"({n_new / dt:.1f} tok/s on CPU)")
+              f"({n_new / dt:.1f} tok/s {where})")
         print("sample row:", out[0, :].tolist()[:48])
         return
 
@@ -280,7 +284,7 @@ def main(argv=None):
     st = sched.stats
     gen = st.generated_tokens
     print(f"[serve/{args.scheduler}] {cfg.name}: {len(comps)} requests, "
-          f"{gen} new tokens in {dt:.2f}s ({gen / dt:.1f} tok/s on CPU)")
+          f"{gen} new tokens in {dt:.2f}s ({gen / dt:.1f} tok/s {where})")
     print(f"  slot-steps executed {st.slot_steps}, useful {st.useful_steps}, "
           f"overhead {st.overhead:.1%}")
     rr = residency.manager.report() if residency is not None else None

@@ -22,8 +22,9 @@ small model and gates it against the single-device reference:
     ``reduce_scatter`` must be BIT-identical to the legacy ``psum`` at the
     same tile plan (same adds, different placement), ``ring`` must sit
     within fp noise, the post-scatter epilogue (bias / fused activation /
-    blocked shuffle) must match the unsharded backend, and the pipelined
-    decode cell must not retrace across repeated steps.
+    blocked shuffle) must match the unsharded backend, bf16 row-parallel
+    matmuls must reduce their partials in f32, and the pipelined decode
+    cell must not retrace across repeated steps.
 
 Usage (tests/test_sharded_backend.py and the CI sharded-smoke job):
   REPRO_SHARD_DEVICES=8 python -m repro.launch.shardcheck \\
@@ -225,6 +226,22 @@ def check_collectives(mesh_shape, execution: str, tol: float) -> list:
             print(f"[shardcheck] collectives[{label}] rule={rule}: "
                   f"scatter==psum bitwise, ring rel-L2 {rel_ring:.1e}, "
                   f"vs-unsharded rel-L2 {rel_ref:.1e}")
+
+    # bf16 activations: each shard's partial MVM reaches the reduction in
+    # f32, as the single-device kernel's accumulator does; partials rounded
+    # to bf16 first sit ~1 bf16 ulp (4e-3) off the unsharded result
+    xb, wb = x.astype(jnp.bfloat16), w.astype(jnp.bfloat16)
+    y_ref = jax.jit(lambda xx: ref_bk.dot(xx, wb, tp_hint="row"))(xb)
+    for c, bk in bks.items():
+        y = jax.jit(lambda xx: bk.dot(xx, wb, tp_hint="row"))(xb)
+        rel_bf16 = _rel_l2(np.asarray(y, np.float32),
+                           np.asarray(y_ref, np.float32))
+        if rel_bf16 > 1e-3:
+            fails.append(f"collectives[bf16 {c}]: sharded vs unsharded "
+                         f"rel-L2 {rel_bf16:.2e} > 1e-3")
+        elif not fails:
+            print(f"[shardcheck] collectives[bf16 {c}]: sharded vs "
+                  f"unsharded rel-L2 {rel_bf16:.1e}")
 
     # --- whole-model decode: scatter vs psum logits + zero-retrace gate ---
     cfg = small_cfg()

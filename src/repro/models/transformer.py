@@ -129,6 +129,17 @@ def init_layer(key, cfg: ModelConfig, mixer_kind: str, ffn_kind: str):
     return p, s
 
 
+def _gather_for_prefill(y, mode, ctx):
+    """Prefill takes each sublayer's output whole before the residual add:
+    a tensor-parallel matmul may leave it "model"-sharded (reduce-scatter),
+    and GSPMD would then partition the next norm's reduction over "model",
+    changing its summation order.  Decode defers that gather to the layer
+    boundary (the collective-compute overlap, ``api._serve_act_pspec``)."""
+    if mode != "prefill" or ctx.get("act_pspec") is None:
+        return y
+    return jax.lax.with_sharding_constraint(y, ctx["act_pspec"])
+
+
 def apply_layer(p, cfg: ModelConfig, h, cache, aux, *, mixer_kind, ffn_kind,
                 mode, causal, pos, ctx, transpose):
     """One pre-norm residual layer.  Returns (h, cache, aux)."""
@@ -213,7 +224,7 @@ def apply_layer(p, cfg: ModelConfig, h, cache, aux, *, mixer_kind, ffn_kind,
                          if mode == "prefill" else None)
     else:
         raise ValueError(mixer_kind)
-    h = h + y
+    h = h + _gather_for_prefill(y, mode, ctx)
     if ffn_kind != "none":
         hn = apply_norm(p["norm2"], h, cfg.norm, cfg.norm_eps)
         if ffn_kind == "moe":
@@ -223,7 +234,7 @@ def apply_layer(p, cfg: ModelConfig, h, cache, aux, *, mixer_kind, ffn_kind,
         else:
             y = apply_mlp(p["ffn"], hn, act=cfg.mlp_act, transpose=transpose,
                           backend=bk)
-        h = h + y
+        h = h + _gather_for_prefill(y, mode, ctx)
     if ctx.get("act_pspec") is not None:
         h = jax.lax.with_sharding_constraint(h, ctx["act_pspec"])
     return h, new_cache, aux

@@ -232,13 +232,15 @@ def test_sharded_parity_1x2():
     1x1 bit-identity, dropped-rule warning surfaced, plus the collective
     gates: reduce_scatter bit-identical to psum (dot-level AND prefill
     logits), post-scatter epilogue (bias / fused activation / blocked
-    shuffle) vs unsharded, zero retrace on the pipelined decode cell."""
+    shuffle) vs unsharded, bf16 row-parallel partials reduced in f32, zero
+    retrace on the pipelined decode cell."""
     out = _run_shardcheck(["--mesh", "1x2", "--execution", "photonic",
                            "--check-dropped", "--collectives"])
     assert "1x1 mesh bit-identical" in out
     assert "dropped-rule warning surfaced" in out
     assert "scatter==psum bitwise" in out
     assert "collectives[blend-shuffle]" in out
+    assert "collectives[bf16 reduce_scatter]" in out
     assert "prefill bitwise" in out
     assert "zero retrace" in out
 
