@@ -1,11 +1,12 @@
-"""repro.obs — dependency-free telemetry subsystem (DESIGN.md
-§Observability).
+"""repro.obs — telemetry subsystem, dependency-free apart from JAX's
+profiler (DESIGN.md §Observability).
 
   * :mod:`repro.obs.metrics` — counters / gauges / mergeable streaming-
     percentile histograms, a :class:`MetricsRegistry` with JSON-snapshot +
     Prometheus-text export, and the global ``enable()`` switch gating
     hot-path instrumentation;
-  * :mod:`repro.obs.tracing` — span/event tracer with Chrome-trace export;
+  * :mod:`repro.obs.tracing` — ``span``, the program's host spans on the
+    profiler's clock, and a span/event tracer with Chrome-trace export;
   * :mod:`repro.obs.meter`   — :class:`PhotonicMeter`, the live
     write-vs-reuse energy/latency ledger over ``core/costmodel.py``;
   * :mod:`repro.obs.stats`   — the shared ``WaveStats``/``ContinuousStats``
@@ -24,7 +25,7 @@ from repro.obs.metrics import (  # noqa: F401
     record_kernel_call, reset_default_registry,
 )
 from repro.obs.tracing import (  # noqa: F401
-    Tracer, default_tracer, enable_tracing,
+    Tracer, default_tracer, enable_tracing, span,
 )
 
 _LAZY = {

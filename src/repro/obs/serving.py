@@ -8,7 +8,8 @@ the timestamps into the serving latency metrics:
     token, queueing included: the number a user feels);
   * ``serve.tpot_ms``  — per-token inter-arrival during decode;
   * ``serve.e2e_ms``   — arrive -> finish;
-  * ``serve.queue_ms`` — arrive -> admission (backpressure visibility);
+  * ``serve.queue_ms`` — submit -> admission, the scheduler's own number
+    (its ``sched.admit`` span carries the same; backpressure visibility);
 
 all as streaming histograms (p50/p95/p99), plus Chrome-trace spans — one
 timeline row per request (``tid`` = rid) — so ``chrome://tracing`` renders
@@ -68,13 +69,16 @@ class RequestTracker:
         self._live[rid] = _ReqTimes(arrive=self._now())
         self.registry.counter("serve.requests.arrived").inc()
 
-    def on_admit(self, rid: int, prompt_len: int, padded_to: int) -> None:
+    def on_admit(self, rid: int, prompt_len: int, padded_to: int,
+                 queued_ms: float) -> None:
+        """``queued_ms`` is the scheduler's own submit-to-admission time,
+        the number its ``sched.admit`` span carries."""
         st = self._live.get(rid)
         if st is None:
             return
-        st.admit = self._now()
+        st.admit = st.arrive + queued_ms * 1e-3
         st.prompt_len, st.padded_to = prompt_len, padded_to
-        self.queue.record((st.admit - st.arrive) * 1e3)
+        self.queue.record(queued_ms)
 
     def on_first_token(self, rid: int) -> None:
         st = self._live.get(rid)

@@ -1,8 +1,16 @@
-"""Span/event tracer with Chrome-trace (``chrome://tracing``) JSON export.
+"""Program spans on the profiler's clock, and a span/event tracer with
+Chrome-trace (``chrome://tracing``) JSON export.
+
+:func:`span` is the one way the program marks host work: it always enters
+``jax.profiler.TraceAnnotation``, so while a profiler session runs (``jax.
+profiler.trace``, the chip benchmark's ``--trace 1``) the span lands in the
+profiler trace on the same clock as the device ops, and costs about what a
+``nullcontext`` does when none runs.  Given an enabled :class:`Tracer` it
+also records the same ``X`` event there.
 
 The request-lifecycle visualization layer: the serving scheduler emits one
 timeline *row per request* (trace ``tid`` = request id) carrying its
-``queue -> prefill -> decode`` spans, plus a row 0 for scheduler steps —
+``queue -> prefill -> decode`` spans, plus row 0 for its ``sched.*`` spans —
 load the exported file in ``chrome://tracing`` / Perfetto and the
 continuous-batching queue becomes a picture (admission waves, slot churn,
 stragglers).
@@ -21,6 +29,8 @@ import collections
 import contextlib
 import json
 import time
+
+from jax import profiler as _profiler
 
 
 class Tracer:
@@ -97,6 +107,22 @@ class Tracer:
     def save(self, path: str) -> None:
         with open(path, "w") as f:
             json.dump(self.chrome_trace(), f)
+
+
+def span(name: str, *, tracer: "Tracer | None" = None, **args):
+    """Context manager marking host work ``name`` with ``args`` (ints,
+    floats, strings): a profiler annotation, whose args become event stats,
+    and with an enabled ``tracer`` the same Chrome ``X`` span."""
+    if tracer is not None and tracer.enabled:
+        return _both(name, tracer, args)
+    return _profiler.TraceAnnotation(name, **args)
+
+
+@contextlib.contextmanager
+def _both(name: str, tracer: "Tracer", args: dict):
+    with _profiler.TraceAnnotation(name, **args), \
+            tracer.span(name, **args):
+        yield
 
 
 # A process-wide disabled tracer: instrumentation sites can always call

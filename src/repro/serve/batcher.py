@@ -14,8 +14,8 @@ which decodes with per-slot positions over a ``serve.slots.SlotPool``
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
+import time
 from typing import Optional
 
 import numpy as np
@@ -27,10 +27,7 @@ from repro.configs.base import ModelConfig
 # WaveStats lives in the shared stats protocol (repro.obs.stats) now —
 # re-exported here so historical imports keep working
 from repro.obs.stats import WaveStats as WaveStats  # noqa: F401
-
-
-def _null():
-    return contextlib.nullcontext()
+from repro.obs.tracing import span
 
 
 @dataclasses.dataclass
@@ -80,12 +77,14 @@ class WaveBatcher:
         self.pad_id = pad_id
         self.temperature = temperature
         self.queue: list[Request] = []
+        self._submitted: dict[int, float] = {}     # rid -> perf_counter
         self.obs = telemetry
         self.stats = WaveStats(
             registry=telemetry.registry if telemetry else None)
 
     def submit(self, req: Request) -> None:
         self.queue.append(req)
+        self._submitted[req.rid] = time.perf_counter()
         if self.obs:
             self.obs.tracker.on_submit(req.rid)
 
@@ -126,14 +125,18 @@ class WaveBatcher:
             # aligned decode then starts all slots together)
             prompts[i, max_prompt - len(r.prompt):] = r.prompt
         extras = wave[0].extras      # every wave member matches (_form_wave)
+        t = time.perf_counter()
+        queued_ms = {r.rid: (t - self._submitted.pop(r.rid)) * 1e3
+                     for r in wave}
         if self.obs:
             for r in wave:
-                self.obs.tracker.on_admit(r.rid, len(r.prompt), max_prompt)
+                self.obs.tracker.on_admit(r.rid, len(r.prompt), max_prompt,
+                                          queued_ms[r.rid])
             if self.obs.meter is not None:
                 self.obs.meter.on_prefill(B * max_prompt)
         tr = self.obs.tracer if self.obs else None
-        with (tr.span("wave", requests=B, max_prompt=max_prompt,
-                      max_new=max_new) if tr else _null()):
+        with span("wave", tracer=tr, requests=B, max_prompt=max_prompt,
+                  max_new=max_new):
             out = self.program.generate(jnp.asarray(prompts), max_new,
                                         extras=extras,
                                         temperature=self.temperature)

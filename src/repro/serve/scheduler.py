@@ -22,9 +22,9 @@ Both schedulers implement the ``Scheduler`` protocol: ``submit`` requests,
 from __future__ import annotations
 
 import collections
-import contextlib
 import dataclasses
 import math
+import time
 from typing import Callable, Optional, Protocol, runtime_checkable
 
 import numpy as np
@@ -40,6 +40,7 @@ from repro.models import transformer as tfm
 # ContinuousStats lives in the shared stats protocol (repro.obs.stats) —
 # re-exported so historical imports keep working
 from repro.obs.stats import ContinuousStats as ContinuousStats  # noqa: F401
+from repro.obs.tracing import span
 from repro.serve.batcher import Completion, Request
 from repro.serve.slots import SlotPool, SlotState
 
@@ -224,11 +225,17 @@ class ContinuousScheduler:
         # their final chunk lands and write_prefill publishes the cache.
         self._prefilling: dict[int, dict] = {}
         self.queue: collections.deque[Request] = collections.deque()
+        # perf_counter at submit, one per queued request in queue order:
+        # the ``queued_ms`` of its ``sched.admit`` span and of the tracker
+        self._queued_at: collections.deque[float] = collections.deque()
         # telemetry: an optional repro.obs.serving.ServingObs — request-
         # lifecycle latency histograms (TTFT/TPOT/e2e), Chrome-trace spans,
         # and the PhotonicMeter write-vs-reuse energy ledger.  The stats
         # counters share its registry so one snapshot carries everything.
         self.obs = telemetry
+        # the program's host spans (obs.tracing.span) always reach a running
+        # profiler session; this Chrome tracer also gets them when enabled
+        self._tracer = telemetry.tracer if telemetry else None
         if (self.residency is not None and self.obs is not None
                 and self.obs.meter is not None):
             # hand the meter's write schedule to the residency manager so
@@ -251,6 +258,7 @@ class ContinuousScheduler:
         if req.max_new < 1:
             raise ValueError("max_new must be >= 1")
         self.queue.append(req)
+        self._queued_at.append(time.perf_counter())
         if self.obs:
             self.obs.tracker.on_submit(req.rid)
 
@@ -265,21 +273,32 @@ class ContinuousScheduler:
     def step(self) -> list[Completion]:
         """Admit (policy-bounded) new requests, advance one prefill chunk
         per staging slot, then decode one token for every in-flight slot.
-        Returns requests completed this step."""
+        Returns requests completed this step.
+
+        Host spans (``obs.tracing.span``): ``sched.step`` around it all;
+        ``sched.admit`` (children ``sched.prefill.dispatch``,
+        ``sched.write_prefill``, ``sched.first_token.wait``) per admission;
+        ``sched.chunk`` per prefill chunk; ``sched.decode.dispatch``,
+        ``sched.decode.wait`` and ``sched.commit`` for the decode.  A
+        ``*.wait`` span is the host blocked on the device; every other
+        span is host work."""
         done: list[Completion] = []
-        n = self.admission.admit_count(queued=len(self.queue),
-                                       free=self.pool.num_free,
-                                       active=self.pool.num_active)
-        for _ in range(n):
-            comp = self._admit_one(self.queue.popleft())
-            if comp is not None:          # max_new == 1: done at prefill
-                done.append(comp)
-        if self._prefilling:
-            done.extend(self._advance_chunks())
-        if self.pool.num_active > len(self._prefilling):
-            done.extend(self._decode_once())
-        if self.obs and self.obs.tracer.enabled:
-            self.obs.tracer.counter("active_slots", self.pool.num_active)
+        with span("sched.step", tracer=self._tracer,
+                  active=self.pool.num_active, queued=len(self.queue)):
+            n = self.admission.admit_count(queued=len(self.queue),
+                                           free=self.pool.num_free,
+                                           active=self.pool.num_active)
+            for _ in range(n):
+                req, t_submit = self.queue.popleft(), self._queued_at.popleft()
+                comp = self._admit_one(req, t_submit)
+                if comp is not None:          # max_new == 1: done at prefill
+                    done.append(comp)
+            if self._prefilling:
+                done.extend(self._advance_chunks())
+            if self.pool.num_active > len(self._prefilling):
+                done.extend(self._decode_once())
+            if self.obs and self.obs.tracer.enabled:
+                self.obs.tracer.counter("active_slots", self.pool.num_active)
         return done
 
     # ------------------------------------------------------------ internals
@@ -293,20 +312,33 @@ class ContinuousScheduler:
         b = self.prefill_bucket
         return min(-(-plen // b) * b, self.pool.max_len)
 
-    def _admit_one(self, req: Request) -> Optional[Completion]:
+    def _admit_one(self, req: Request,
+                   t_submit: float) -> Optional[Completion]:
         plen = len(req.prompt)
-        if (self._chunkable and not req.extras
-                and plen > self.prefill_chunk):
-            self._start_chunked(req)
-            return None
-        bucket = self._bucket(plen)
+        queued_ms = (time.perf_counter() - t_submit) * 1e3
+        chunked = (self._chunkable and not req.extras
+                   and plen > self.prefill_chunk)
+        W = self.prefill_chunk
+        rows = -(-plen // W) * W if chunked else self._bucket(plen)
+        with span("sched.admit", tracer=self._tracer, rid=req.rid,
+                  prompt_len=plen, rows=rows, queued_ms=queued_ms):
+            if chunked:
+                self._start_chunked(req, rows, queued_ms)
+                return None
+            return self._prefill(req, rows, queued_ms)
+
+    def _prefill(self, req: Request, bucket: int,
+                 queued_ms: float) -> Optional[Completion]:
+        """Monolithic prefill of one prompt at its compile bucket, straight
+        into its slot; its first token is sampled here."""
+        plen = len(req.prompt)
         state = SlotState(rid=req.rid, prompt_len=plen, max_new=req.max_new,
                           eos_id=req.eos_id,
                           prompt=np.asarray(req.prompt, np.int32),
                           padded_to=bucket)
         slot = self.pool.allocate(state)
         if self.obs:
-            self.obs.tracker.on_admit(req.rid, plen, bucket)
+            self.obs.tracker.on_admit(req.rid, plen, bucket, queued_ms)
             if self.obs.meter is not None:
                 # the prefill streams `bucket` positions through the stack
                 self.obs.meter.on_prefill(bucket)
@@ -321,13 +353,10 @@ class ContinuousScheduler:
             batch.update(req.extras)
         # one jitted prefill per compile bucket — the cell cache is keyed on
         # the static cache_len, shared across schedulers via repro.api
-        logits, caches = self.program.prefill(
-            batch, bucket, last=jnp.asarray([plen - 1], jnp.int32))
-        self.pool.write_prefill(slot, caches, plen)
-        tok = int(np.asarray(api.sample(logits, self.cfg.vocab_size,
-                                        self._next_key(),
-                                        self.temperature))[0])
-        self._cur[slot, 0] = tok
+        with span("sched.prefill.dispatch", tracer=self._tracer):
+            logits, caches = self.program.prefill(
+                batch, bucket, last=jnp.asarray([plen - 1], jnp.int32))
+        tok = self._publish(slot, caches, logits, plen)
         self.stats.requests += 1
         self.stats.prefills += 1
         self.stats.prompt_tokens += plen
@@ -336,23 +365,34 @@ class ContinuousScheduler:
         self.stats.useful_steps += plen
         return self._commit_token(slot, tok)
 
-    def _start_chunked(self, req: Request) -> None:
+    def _publish(self, slot: int, caches, logits, plen: int) -> int:
+        """Write a finished prefill's cache into the pool and read its first
+        token back to the host."""
+        with span("sched.write_prefill", tracer=self._tracer):
+            self.pool.write_prefill(slot, caches, plen)
+        with span("sched.first_token.wait", tracer=self._tracer):
+            tok = int(np.asarray(api.sample(logits, self.cfg.vocab_size,
+                                            self._next_key(),
+                                            self.temperature))[0])
+        self._cur[slot, 0] = tok
+        return tok
+
+    def _start_chunked(self, req: Request, padded: int,
+                       queued_ms: float) -> None:
         """Allocate a slot and stage a chunked prefill: the prompt runs in
         ``prefill_chunk``-wide pieces (tail zero-padded, causally invisible),
         one chunk per scheduler step, into a batch-1 staging cache at the
         pool's max_len — so every chunk of every request reuses the one
         compiled cell per chunk width.  The slot joins the decode batch only
         when the last chunk lands (``_advance_chunks``)."""
-        W = self.prefill_chunk
         plen = len(req.prompt)
-        padded = -(-plen // W) * W
         state = SlotState(rid=req.rid, prompt_len=plen, max_new=req.max_new,
                           eos_id=req.eos_id,
                           prompt=np.asarray(req.prompt, np.int32),
                           padded_to=padded)
         slot = self.pool.allocate(state)
         if self.obs:
-            self.obs.tracker.on_admit(req.rid, plen, padded)
+            self.obs.tracker.on_admit(req.rid, plen, padded, queued_ms)
             if self.obs.meter is not None:
                 # the chunks stream `padded` positions through the stack
                 self.obs.meter.on_prefill(padded)
@@ -360,9 +400,10 @@ class ContinuousScheduler:
             self.residency.on_prefill(padded)
         toks = np.full((1, padded), self.pad_id, np.int32)
         toks[0, :plen] = req.prompt
+        with span("sched.prefill.dispatch", tracer=self._tracer):
+            caches = self.program.empty_caches(1, self.pool.max_len)
         self._prefilling[slot] = {
-            "state": state, "tokens": toks, "off": 0,
-            "caches": self.program.empty_caches(1, self.pool.max_len)}
+            "state": state, "tokens": toks, "off": 0, "caches": caches}
         self.stats.requests += 1
         self.stats.prefills += 1
         self.stats.prompt_tokens += plen
@@ -376,29 +417,25 @@ class ContinuousScheduler:
         fires here), and hand the slot to the decode loop."""
         done: list[Completion] = []
         W = self.prefill_chunk
-        tr = self.obs.tracer if self.obs else None
         for slot in sorted(self._prefilling):
             st = self._prefilling[slot]
             state, off = st["state"], st["off"]
             last = off + W >= st["tokens"].shape[1]
             # plen-1 always falls inside the final (padded) chunk
             idx = state.prompt_len - 1 - off if last else W - 1
-            with (tr.span("prefill_chunk", rid=state.rid, off=off)
-                  if tr and tr.enabled else contextlib.nullcontext()):
+            with span("sched.chunk", tracer=self._tracer, rid=state.rid,
+                      off=off, last=int(last)):
                 logits, st["caches"] = self.program.prefill_chunk(
                     jnp.asarray(st["tokens"][:, off:off + W]), st["caches"],
                     off, last=jnp.asarray([idx], jnp.int32))
-            st["off"] = off + W
-            self.stats.prefill_chunks += 1
-            if not last:
-                continue
-            del self._prefilling[slot]
-            self.pool.write_prefill(slot, st["caches"], state.prompt_len)
-            tok = int(np.asarray(api.sample(logits, self.cfg.vocab_size,
-                                            self._next_key(),
-                                            self.temperature))[0])
-            self._cur[slot, 0] = tok
-            comp = self._commit_token(slot, tok)
+                st["off"] = off + W
+                self.stats.prefill_chunks += 1
+                if not last:
+                    continue
+                del self._prefilling[slot]
+                tok = self._publish(slot, st["caches"], logits,
+                                    state.prompt_len)
+                comp = self._commit_token(slot, tok)
             if comp is not None:
                 done.append(comp)
         return done
@@ -436,42 +473,44 @@ class ContinuousScheduler:
         return None
 
     def _decode_once(self) -> list[Completion]:
-        # staging (chunk-prefilling) slots ride the full-pool step as idle
-        # lanes: their position is 0, so the step's garbage delta write at
-        # position 0 is dead data — write_prefill later overwrites the whole
-        # slot — and they must not commit tokens or advance
-        active = [s for s in self.pool.active_slots()
-                  if s not in self._prefilling]
-        self.stats.observe_active(len(active))
-        if self.obs and self.obs.meter is not None:
-            # the fused decode step runs the FULL pool through the stack —
-            # idle slots ride along padded (that waste is what the
-            # occupancy histogram + idle_fraction expose)
-            self.obs.meter.on_decode_step(self.pool.capacity)
-        if self.residency is not None:
-            self.residency.on_decode_step(self.pool.capacity)
-        if self.calibration is not None:
-            self.calibration.on_step()
-        tr = self.obs.tracer if self.obs else None
-        with (tr.span("decode_step", active=len(active),
-                      capacity=self.pool.capacity)
-              if tr and tr.enabled else contextlib.nullcontext()):
+        tr = self._tracer
+        # sched.decode.dispatch: all host work up to the decode's enqueue
+        with span("sched.decode.dispatch", tracer=tr):
+            # staging (chunk-prefilling) slots ride the full-pool step as
+            # idle lanes: their position is 0, so the step's garbage delta
+            # write at position 0 is dead data — write_prefill later
+            # overwrites the whole slot — and they must not commit tokens
+            # or advance
+            active = [s for s in self.pool.active_slots()
+                      if s not in self._prefilling]
+            self.stats.observe_active(len(active))
+            if self.obs and self.obs.meter is not None:
+                # the fused decode step runs the FULL pool through the
+                # stack — idle slots ride along padded (that waste is what
+                # the occupancy histogram + idle_fraction expose)
+                self.obs.meter.on_decode_step(self.pool.capacity)
+            if self.residency is not None:
+                self.residency.on_decode_step(self.pool.capacity)
+            if self.calibration is not None:
+                self.calibration.on_step()
             nxt, self.pool.caches = self.program.decode_sample(
                 jnp.asarray(self._cur), self.pool.caches,
                 self.pool.position_vector(), key=self._next_key(),
                 temperature=self.temperature)
-        nxt = np.asarray(nxt)
+        with span("sched.decode.wait", tracer=tr):
+            nxt = np.asarray(nxt)
         self.stats.decode_steps += 1
         self.stats.slot_steps += self.pool.capacity
         self.stats.idle_slot_steps += self.pool.capacity - len(active)
         done = []
-        for slot in active:
-            # the step wrote this slot's pending token at its position
-            self.pool.advance(slot)
-            self.stats.useful_steps += 1
-            comp = self._commit_token(slot, int(nxt[slot]))
-            if comp is None:
-                self._cur[slot, 0] = int(nxt[slot])
-            else:
-                done.append(comp)
+        with span("sched.commit", tracer=tr):
+            for slot in active:
+                # the step wrote this slot's pending token at its position
+                self.pool.advance(slot)
+                self.stats.useful_steps += 1
+                comp = self._commit_token(slot, int(nxt[slot]))
+                if comp is None:
+                    self._cur[slot, 0] = int(nxt[slot])
+                else:
+                    done.append(comp)
         return done

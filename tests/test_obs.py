@@ -364,7 +364,7 @@ class TestTracing:
         t = RequestTracker(reg, tr)
         for rid in (0, 1):
             t.on_submit(rid)
-            t.on_admit(rid, prompt_len=5, padded_to=8)
+            t.on_admit(rid, prompt_len=5, padded_to=8, queued_ms=0.5)
             t.on_first_token(rid)
             for _ in range(3):
                 t.on_token(rid)
@@ -386,7 +386,7 @@ class TestTracing:
     def test_first_token_does_not_pollute_tpot(self):
         t = RequestTracker(metrics_lib.MetricsRegistry())
         t.on_submit(0)
-        t.on_admit(0, 4, 4)
+        t.on_admit(0, 4, 4, 0.5)
         t.on_first_token(0)
         assert t.tpot.count == 0           # TTFT only — no 0ms TPOT sample
         t.on_token(0)
@@ -400,7 +400,7 @@ class TestSchema:
     def test_snapshot_validates(self):
         obs = ServingObs.create(trace=False)
         obs.tracker.on_submit(0)
-        obs.tracker.on_admit(0, 4, 8)
+        obs.tracker.on_admit(0, 4, 8, 0.5)
         obs.tracker.on_first_token(0)
         obs.tracker.on_finish(0)
         snap = obs.snapshot()
@@ -437,7 +437,7 @@ class TestSchema:
         assert snap["histograms"] == {}   # meters registered, no samples
         assert validate(snap, load_schema()) == []
         obs.tracker.on_submit(0)
-        obs.tracker.on_admit(0, 4, 8)
+        obs.tracker.on_admit(0, 4, 8, 0.5)
         obs.tracker.on_first_token(0)
         snap2 = obs.snapshot()
         assert "serve.ttft_ms" in snap2["histograms"]
@@ -527,8 +527,11 @@ class TestServingIntegration:
         doc = obs.tracer.chrome_trace()
         evs = doc["traceEvents"]
         names = {e["name"] for e in evs}
-        assert {"queue", "prefill", "decode", "finish",
-                "decode_step", "active_slots"} <= names
+        assert {"queue", "prefill", "decode", "finish", "active_slots",
+                "sched.step", "sched.admit", "sched.prefill.dispatch",
+                "sched.write_prefill", "sched.first_token.wait",
+                "sched.decode.dispatch", "sched.decode.wait",
+                "sched.commit"} <= names
         # one timeline row per request (tid == rid), named
         named_rows = {e["tid"] for e in evs if e["ph"] == "M"}
         assert named_rows == set(range(n))
