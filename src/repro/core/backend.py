@@ -71,12 +71,12 @@ from repro.core import obu
 from repro.core.photonic import a8_scale_from_amax
 from repro.obs import metrics as _metrics
 from repro.sharding import partition as _partition
-from repro.core.prepared import (PreparedTensor, quantize_weight,
+from repro.core.prepared import (BankLayer, PreparedTensor, quantize_weight,
                                  quantize_weight_t)
 from repro.kernels import flash_attention as _fa
 from repro.kernels import ops
 from repro.kernels.platform import default_interpret
-from repro.kernels.photonic_mvm import tile_plan
+from repro.kernels.photonic_mvm import reads_stack_in_place, tile_plan
 
 EXECUTIONS = ("xla", "photonic")
 
@@ -337,7 +337,7 @@ class Backend:
         ``Program.build``).  ``tp_hint="row"`` marks a pair-second matmul
         for the sharded dispatch (see :func:`partition_rule`); it has no
         effect off-mesh."""
-        if isinstance(w, PreparedTensor):
+        if isinstance(w, (PreparedTensor, BankLayer)):
             return self.dot_prepared(x, w, transpose=transpose, bias=bias,
                                      block_perm=block_perm, block=block,
                                      activation=activation, tp_hint=tp_hint)
@@ -356,13 +356,16 @@ class Backend:
                                      block=block, activation=activation,
                                      tp_hint=tp_hint, bank_tag=None)
 
-    def dot_prepared(self, x, prep: PreparedTensor, *,
-                     transpose: bool = False, bias=None, block_perm=None,
-                     block: int = 0, activation=None, tp_hint=None):
-        """``dot`` against an already-programmed bank: no in-step weight
+    def dot_prepared(self, x, prep, *, transpose: bool = False, bias=None,
+                     block_perm=None, block: int = 0, activation=None,
+                     tp_hint=None):
+        """``dot`` against an already-programmed bank (a ``PreparedTensor``
+        or a ``BankLayer`` of a stacked one): no in-step weight
         quantization.  The transposed orientation uses the bank's per-row
         image (``wq_t``/``scale_t``) — the same array the optical transpose
-        illuminates from the orthogonal port."""
+        illuminates from the orthogonal port.  The fused single-device
+        kernel reads a ``BankLayer``'s tiles in place in its stack; every
+        other path takes the layer's slice."""
         if not self.is_photonic:
             # xla fallback: dequantize the programmed image (W8 numerics
             # preserved) and run the dot_general path.  Only hit when an
@@ -375,24 +378,29 @@ class Backend:
                      * (prep.scale / 127.0)[..., None, :]).astype(x.dtype)
             y = obu.blend_dot(x, w, transpose=transpose)
             return _epilogue_xla(y, bias, block_perm, block, activation)
-        if transpose:
-            if prep.shape[-1] != x.shape[-1]:
-                raise ValueError(f"transpose blend needs square-compatible "
-                                 f"dims, got x{x.shape} w{prep.shape}")
-            wq, wscale = prep.wq_t, prep.scale_t
-        else:
-            wq, wscale = prep.wq, prep.scale
+        if transpose and prep.shape[-1] != x.shape[-1]:
+            raise ValueError(f"transpose blend needs square-compatible "
+                             f"dims, got x{x.shape} w{prep.shape}")
+        layer = None
+        if (isinstance(prep, BankLayer) and self.fused
+                and not self.mesh_active and not self.noise_active):
+            layer, prep = prep.index, prep.stack
+        wq, wscale = ((prep.wq_t, prep.scale_t) if transpose
+                      else (prep.wq, prep.scale))
         return self._photonic_matmul(x, wq, wscale, transpose=transpose,
                                      bias=bias, block_perm=block_perm,
                                      block=block, activation=activation,
-                                     tp_hint=tp_hint, bank_tag=prep.tag)
+                                     tp_hint=tp_hint, bank_tag=prep.tag,
+                                     layer=layer)
 
     def _photonic_matmul(self, x, wq, wscale, *, transpose, bias,
                          block_perm, block, activation, tp_hint=None,
-                         bank_tag=None):
+                         bank_tag=None, layer=None):
         """Shared photonic dispatch: resolve the tile plan from the actual
         operand shapes, then run either the fused megakernel or the split
-        quantize -> MVM -> blend pipeline at that same plan.
+        quantize -> MVM -> blend pipeline at that same plan.  With
+        ``layer`` (fused, single device, noise off), ``wq``/``wscale`` are a
+        stacked bank that the kernel reads layer ``layer`` of in place.
 
         With an enabled fault model (``self.noise``), the call reroutes to
         the noisy split pipeline — bit-exact MVM, ``core/noise.py``
@@ -423,13 +431,17 @@ class Backend:
         # this counts the Pallas calls compiled into each cell, once per
         # (re)trace, keyed by the resolved tile plan
         kind = "fused" if self.fused else "split"
+        if layer is not None and reads_stack_in_place(
+                K, N, bk, bn, block if block_perm is not None else 0):
+            kind = "fused_stacked"
         _metrics.record_kernel_call(kind, bm, bk, bn)
         with jax.named_scope(f"photonic.{kind}.{bm}x{bk}x{bn}"):
             if self.fused:
                 return ops.photonic_matmul_fused(
                     x, wq, wscale, transpose=transpose, bias=bias,
                     block_perm=block_perm, block=block,
-                    activation=activation or "none", bm=bm, bk=bk, bn=bn)
+                    activation=activation or "none", bm=bm, bk=bk, bn=bn,
+                    layer=layer)
             mm = (ops.photonic_matmul_prepared_t if transpose
                   else ops.photonic_matmul_prepared)
             y = mm(x, wq, wscale, bm=bm, bk=bk, bn=bn)
@@ -609,7 +621,7 @@ class Backend:
         (T, ..., k) @ w (k, n).  Photonic: the weight is programmed once and
         stays VMEM-resident while the T streams pass (the write-once /
         reuse-T-times schedule as a kernel)."""
-        if isinstance(w, PreparedTensor):
+        if isinstance(w, (PreparedTensor, BankLayer)):
             return self.reuse_dot_prepared(x_stack, w)
         if not self.is_photonic:
             return obu.blend_dot(x_stack, w, transpose=False)
@@ -624,7 +636,7 @@ class Backend:
             y = ops.reuse_resident_matmul(x_stack, w, bm=bm, bn=bn)
             return self._perturb_reuse(y, bank_tag=None)
 
-    def reuse_dot_prepared(self, x_stack, prep: PreparedTensor):
+    def reuse_dot_prepared(self, x_stack, prep):
         """Reuse-resident matmul against a programmed bank (the fully
         write-once form: neither the weight fetch nor its quantization
         repeats across the T streams)."""

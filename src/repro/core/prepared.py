@@ -136,8 +136,8 @@ class PreparedTensor:
     no rewrite: ``.shape`` reports the logical (fp) shape, ``.astype`` is a
     no-op (a programmed bank has no dtype to cast — readout gain handles
     that), and ``x[i]`` slices every field's leading axis (MoE banks index
-    their basic-expert dimension; the PRM scan slices the R axis the same
-    way via the pytree protocol)."""
+    their basic-expert dimension; the PRM scan reads the R axis through a
+    :class:`BankLayer` instead)."""
 
     wq: jax.Array            # int8 (..., K, N), per-column quantized
     scale: jax.Array         # f32  (..., N)
@@ -205,6 +205,64 @@ class PreparedTensor:
         return cls(wq=wfull, scale=P(*lead, nax), wq_t=wfull,
                    scale_t=P(*lead, kax), w0_colsum=P(*lead, nax),
                    w0_rowsum_t=P(*lead, kax), tag=tag)
+
+
+@jax.tree_util.register_pytree_node_class
+@dataclasses.dataclass(frozen=True)
+class BankLayer:
+    """Layer ``index`` of a stacked bank, read where the bank lies.
+
+    ``core/sharing.run_stack`` hands these to its block function instead of
+    scanning over the stacked banks: a ``pallas_call`` cannot fuse a slice
+    of its operand, so a per-layer ``PreparedTensor`` would be copied out of
+    the stack before every kernel call.  The view keeps the
+    ``PreparedTensor`` surface — per-layer ``shape``, the fields as the
+    dynamic slice of the stack, ``x[i]`` — so every consumer that does not
+    know it gets exactly that slice; only the fused single-device photonic
+    dot (``Backend.dot_prepared``) passes ``stack`` and ``index`` to the
+    kernel, which reads the layer's tiles in place."""
+
+    stack: PreparedTensor    # (R, ...) stacked bank
+    index: jax.Array         # traced int32 scalar, the layer
+
+    def tree_flatten(self):
+        return (self.stack, self.index), None
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children)
+
+    def _at(self, field: jax.Array) -> jax.Array:
+        return jax.lax.dynamic_index_in_dim(field, self.index, 0,
+                                            keepdims=False)
+
+    def layer(self) -> PreparedTensor:
+        return jax.tree.map(self._at, self.stack)
+
+    wq = property(lambda self: self._at(self.stack.wq))
+    scale = property(lambda self: self._at(self.stack.scale))
+    wq_t = property(lambda self: self._at(self.stack.wq_t))
+    scale_t = property(lambda self: self._at(self.stack.scale_t))
+    w0_colsum = property(lambda self: self._at(self.stack.w0_colsum))
+    w0_rowsum_t = property(lambda self: self._at(self.stack.w0_rowsum_t))
+
+    @property
+    def tag(self):
+        return self.stack.tag
+
+    @property
+    def shape(self):
+        return self.stack.shape[1:]
+
+    @property
+    def ndim(self):
+        return self.stack.ndim - 1
+
+    def astype(self, dtype):
+        return self
+
+    def __getitem__(self, idx):
+        return self.layer()[idx]
 
 
 def is_prepared(w: Any) -> bool:

@@ -2,7 +2,9 @@
 
 A stack of ``depth = R*T`` logical blocks is executed as
 
-    scan over R physical blocks            (params are scan xs)
+    scan over R physical blocks            (fp params are scan xs; prepared
+                                            banks stay loop-invariant and
+                                            are read at the block index)
       unrolled loop over T reuses          (params loop-INVARIANT -> weights
                                             stay resident; OBU transform per t)
 
@@ -27,6 +29,7 @@ import jax.numpy as jnp
 
 from repro.core import backend as backend_lib
 from repro.core import obu
+from repro.core.prepared import BankLayer, PreparedTensor
 from repro.core.prm import ReuseConfig, ReusePlan, no_reuse
 
 
@@ -36,6 +39,23 @@ def tree_index(tree, i):
 
 def tree_stack(trees):
     return jax.tree.map(lambda *xs: jnp.stack(xs, axis=0), *trees)
+
+
+def _split_banks(params):
+    """Split stacked params into (scan xs, rebuild): prepared banks leave
+    the xs — a scan would dynamic-slice them, and a ``pallas_call`` cannot
+    fuse that slice, so every layer's int8 bank would be copied out before
+    each kernel call — and come back as ``BankLayer`` views of the whole
+    stack at the traced block index ``r``."""
+    is_bank = lambda v: isinstance(v, PreparedTensor)
+    leaves, treedef = jax.tree.flatten(params, is_leaf=is_bank)
+    banks = [v if is_bank(v) else None for v in leaves]
+    xs = [None if is_bank(v) else v for v in leaves]
+
+    def rebuild(xs_r, r):
+        return treedef.unflatten([BankLayer(b, r) if b is not None else v
+                                  for b, v in zip(banks, xs_r)])
+    return xs, rebuild
 
 
 @dataclasses.dataclass(frozen=True)
@@ -103,6 +123,14 @@ BlockFn = Callable[..., tuple]
 #   accumulator (e.g. MoE load-balance loss) threaded through the scan.
 
 
+def _cache_at(cache_leaf, r, t):
+    """``cache_leaf[r, t]`` of a carried [R, T, ...] buffer at a traced
+    block ``r`` and a static reuse ``t``: one dynamic slice."""
+    start = (r, t) + (0,) * (cache_leaf.ndim - 2)
+    return jax.lax.dynamic_slice(cache_leaf, start,
+                                 (1, 1) + cache_leaf.shape[2:])[0, 0]
+
+
 def _delta_update(cache_leaf, delta, r, t, pos):
     """Write a block_fn cache update back into the carried [R, T, ...] buffer.
 
@@ -148,7 +176,8 @@ def run_stack(block_fn: BlockFn, params: Any, x: jax.Array,
       block_fn: applies ONE basic block (may itself contain several layers —
         block-wise granularity).  Receives a *static* ``transpose`` flag and
         ``reuse_index``.
-      params:  pytree with leading axis R (= shared.num_physical).
+      params:  pytree with leading axis R (= shared.num_physical).  Its
+        ``PreparedTensor`` banks reach block_fn as ``BankLayer`` views.
       x:       activations (..., channels).
       shared:  the static schedule.
       cache:   optional pytree with leading axes [R, T, ...] of per-logical-
@@ -193,26 +222,30 @@ def run_stack(block_fn: BlockFn, params: Any, x: jax.Array,
     reuse_fns = [jax.checkpoint(one_reuse(t)) if remat else one_reuse(t)
                  for t in range(T)]
 
-    def body(h, aux, p_r, cache_r):
+    def body(h, aux, p_r, cache_at):
         new_cache = []
         for t in range(T):
-            c_t = tree_index(cache_r, t) if have_cache else None
+            c_t = cache_at(t) if have_cache else None
             h, aux, c_t = reuse_fns[t](h, aux, p_r, c_t)
             new_cache.append(c_t)
         return h, aux, (new_cache if have_cache else None)
 
+    R = shared.num_physical
+    p_xs, rebuild = _split_banks(params)
+
     if have_cache and decode_pos is not None:
         # ---- decode: cache as in-place carry, delta writes ----
-        R = shared.num_physical
-
         def outer_carry(carry, xs):
             h, aux, cache_all = carry
             p_r, r = xs
-            cache_r = jax.tree.map(
-                lambda c: jax.lax.dynamic_index_in_dim(c, r, 0,
-                                                       keepdims=False),
-                cache_all)
-            h, aux, updates = body(h, aux, p_r, cache_r)
+            p_r = rebuild(p_r, r)
+            # each reuse reads its own [r, t] slice of the carried cache: a
+            # slice of the block's [r] (all T reuses) has T consumers, and
+            # XLA copies it out whole rather than fuse it into each
+            h, aux, updates = body(
+                h, aux, p_r,
+                lambda t: jax.tree.map(lambda c: _cache_at(c, r, t),
+                                       cache_all))
             for t, up_t in enumerate(updates):
                 cache_all = jax.tree.map(
                     lambda c, u: _delta_update(c, u, r, t, decode_pos),
@@ -220,18 +253,20 @@ def run_stack(block_fn: BlockFn, params: Any, x: jax.Array,
             return (h, aux, cache_all), None
 
         (x, aux, cache), _ = jax.lax.scan(
-            outer_carry, (x, aux0, cache), (params, jnp.arange(R)),
+            outer_carry, (x, aux0, cache), (p_xs, jnp.arange(R)),
             unroll=unroll_scan)
         return x, cache, aux
 
     def outer(carry, xs):
         h, aux = carry
-        p_r, cache_r = xs
-        h, aux, out_cache = body(h, aux, p_r, cache_r)
+        p_r, cache_r, r = xs
+        h, aux, out_cache = body(h, aux, rebuild(p_r, r),
+                                 lambda t: tree_index(cache_r, t))
         return (h, aux), (tree_stack(out_cache)
                           if out_cache is not None else None)
 
-    (x, aux), new_cache = jax.lax.scan(outer, (x, aux0), (params, cache),
+    (x, aux), new_cache = jax.lax.scan(outer, (x, aux0),
+                                       (p_xs, cache, jnp.arange(R)),
                                        unroll=unroll_scan)
     return x, new_cache, aux
 

@@ -134,7 +134,7 @@ def reuse_resident_matmul_prepared(x_stack, wq, wscale, *, bm=128, bn=128,
 def photonic_matmul_fused(x, wq, wscale, *, transpose=False, bias=None,
                           block_perm=None, block=0, activation="none",
                           bm=128, bk=128, bn=128, qmax=127.0, x_scale=None,
-                          out_dtype=None):
+                          out_dtype=None, layer=None):
     """One-``pallas_call`` serving matmul against a prepared bank.
 
     x: fp (..., k); wq/wscale: a prepared orientation — (k, n)/per-column,
@@ -147,15 +147,17 @@ def photonic_matmul_fused(x, wq, wscale, *, transpose=False, bias=None,
     ``photonic_mvm._kernel_fused``).  ``x_scale`` overrides the A8 scale
     (the shard_map'd backend passes the global activation's scale so a
     partitioned matmul's shards all quantize on the single-device grid).
-    ``out_dtype`` defaults to x's dtype."""
+    ``out_dtype`` defaults to x's dtype.  With ``layer``, wq/wscale are a
+    stacked bank (R, ...) read at that layer in place."""
     xscale = a8_scale(x) if x_scale is None else x_scale
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
-    n_out = wq.shape[0] if transpose else wq.shape[1]
+    n_out = wq.shape[-2] if transpose else wq.shape[-1]
     perm = tuple(int(v) for v in block_perm) if block_perm is not None \
         else None
     y = _pm.photonic_mvm_fused(
-        x2, wq, xscale, wscale.reshape(-1), bias=bias, bm=bm, bk=bk, bn=bn,
+        x2, wq, xscale, wscale.reshape(*wq.shape[:-2], n_out), bias=bias,
+        layer=layer, bm=bm, bk=bk, bn=bn,
         qmax=qmax, transpose=transpose, activation=activation,
         block_perm=perm, block=block, out_dtype=out_dtype or x.dtype)
     return y.reshape(*lead, n_out)

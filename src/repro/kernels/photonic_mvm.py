@@ -439,11 +439,28 @@ def _out_block_index(block_perm, block: int, N: int, bn: int) -> np.ndarray:
     return fine.reshape(-1).astype(np.int32)
 
 
+def reads_stack_in_place(K: int, N: int, bk: int, bn: int,
+                         block: int = 0) -> bool:
+    """Whether the fused kernel reads a stacked (R, K, N) bank in place at
+    this tile plan: neither weight axis needs padding (padding would copy
+    the whole stack).  ``block`` is the blocked-shuffle size, which narrows
+    the column tile to ``gcd(bn, block)``."""
+    if block > 0:
+        bn = math.gcd(bn, block)
+    return K % bk == 0 and N % bn == 0
+
+
+def _kernel_fused_stacked(oidx_ref, layer_ref, *refs, **kw):
+    """``_kernel_fused`` behind a second scalar-prefetch operand: the layer
+    index is consumed by the weight and scale index maps, not the body."""
+    _kernel_fused(oidx_ref, *refs, **kw)
+
+
 @functools.partial(jax.jit, static_argnames=(
     "bm", "bk", "bn", "qmax", "transpose", "activation", "block_perm",
     "block", "interpret", "out_dtype"))
-def photonic_mvm_fused(x, wq, x_scale, w_scale, *, bias=None, bm=128, bk=128,
-                       bn=128, qmax=127.0, transpose=False,
+def photonic_mvm_fused(x, wq, x_scale, w_scale, *, bias=None, layer=None,
+                       bm=128, bk=128, bn=128, qmax=127.0, transpose=False,
                        activation="none", block_perm=None, block=0,
                        interpret=None, out_dtype=jnp.float32):
     """The decode-path megakernel: one ``pallas_call`` for
@@ -458,14 +475,23 @@ def photonic_mvm_fused(x, wq, x_scale, w_scale, *, bias=None, bm=128, bk=128,
     block_perm: optional tuple — output block ``q`` carries computed block
     ``block_perm[q]``, realized purely by the output BlockSpec's
     scalar-prefetched index map.  Returns (M, N) ``out_dtype``.
+
+    With ``layer`` (a traced int32 scalar), ``wq`` is a stacked bank
+    (R, K, N) — (R, N, K) transposed — with ``w_scale`` (R, N), and the
+    kernel multiplies by layer ``layer``: the weight and scale index maps
+    read its tiles straight out of the stack through a second
+    scalar-prefetch operand, so no per-layer copy of the bank is ever
+    written.  Bitwise equal to passing ``wq[layer]``, ``w_scale[layer]``.
+    When a weight axis would need padding (:func:`reads_stack_in_place`)
+    the layer is sliced out first instead, never the whole stack padded.
     """
     if interpret is None:
         interpret = default_interpret()
     M, K = x.shape
     if transpose:
-        N, K2 = wq.shape
+        N, K2 = wq.shape[-2:]
     else:
-        K2, N = wq.shape
+        K2, N = wq.shape[-2:]
     assert K == K2
     if block_perm is not None:
         if block <= 0:
@@ -474,27 +500,46 @@ def photonic_mvm_fused(x, wq, x_scale, w_scale, *, bias=None, bm=128, bk=128,
         if N % block != 0:
             raise ValueError(f"blocked shuffle needs C % block == 0, got "
                              f"C={N}, block={block}")
+    if layer is not None and not reads_stack_in_place(K, N, bk, bn):
+        wq = jax.lax.dynamic_index_in_dim(wq, layer, 0, keepdims=False)
+        w_scale = jax.lax.dynamic_index_in_dim(w_scale, layer, 0,
+                                               keepdims=False)
+        layer = None
+    stacked = layer is not None
     x_p = _pad_to(_pad_to(x, bm, 0), bk, 1)
-    if transpose:
-        wq_p = _pad_to(_pad_to(wq, bn, 0), bk, 1)
-        Np = wq_p.shape[0]
+    if stacked:
+        wq_p = wq                        # aligned: read in place
+        ws_p = w_scale.reshape(wq.shape[0], 1, N)
     else:
-        wq_p = _pad_to(_pad_to(wq, bk, 0), bn, 1)
-        Np = wq_p.shape[1]
-    ws_p = _pad_to(w_scale.reshape(1, N), bn, 1)
+        wq_p = (_pad_to(_pad_to(wq, bn, 0), bk, 1) if transpose
+                else _pad_to(_pad_to(wq, bk, 0), bn, 1))
+        ws_p = _pad_to(w_scale.reshape(1, N), bn, 1)
+    Np = wq_p.shape[-2] if transpose else wq_p.shape[-1]
     Mp, Kp = x_p.shape
     grid = (Mp // bm, Np // bn, Kp // bk)
     if block_perm is not None:
         oidx = _out_block_index(block_perm, block, N, bn)
     else:
         oidx = np.arange(Np // bn, dtype=np.int32)
-    w_spec = (pl.BlockSpec((bn, bk), lambda i, j, k, oi: (j, k)) if transpose
-              else pl.BlockSpec((bk, bn), lambda i, j, k, oi: (k, j)))
+    # index maps take every scalar-prefetch ref: (oidx,) or (oidx, layer)
+    if stacked:
+        w_spec = (pl.BlockSpec((None, bn, bk),
+                               lambda i, j, k, oi, li: (li[0], j, k))
+                  if transpose else
+                  pl.BlockSpec((None, bk, bn),
+                               lambda i, j, k, oi, li: (li[0], k, j)))
+        ws_spec = pl.BlockSpec((None, 1, bn),
+                               lambda i, j, k, oi, li: (li[0], 0, j))
+    else:
+        w_spec = (pl.BlockSpec((bn, bk), lambda i, j, k, *_: (j, k))
+                  if transpose else
+                  pl.BlockSpec((bk, bn), lambda i, j, k, *_: (k, j)))
+        ws_spec = pl.BlockSpec((1, bn), lambda i, j, k, *_: (0, j))
     in_specs = [
-        pl.BlockSpec((bm, bk), lambda i, j, k, oi: (i, k)),
+        pl.BlockSpec((bm, bk), lambda i, j, k, *_: (i, k)),
         w_spec,
-        pl.BlockSpec((1, 1), lambda i, j, k, oi: (0, 0)),
-        pl.BlockSpec((1, bn), lambda i, j, k, oi: (0, j)),
+        pl.BlockSpec((1, 1), lambda i, j, k, *_: (0, 0)),
+        ws_spec,
     ]
     operands = [x_p, wq_p,
                 jnp.reshape(x_scale, (1, 1)).astype(jnp.float32),
@@ -504,24 +549,30 @@ def photonic_mvm_fused(x, wq, x_scale, w_scale, *, bias=None, bm=128, bk=128,
         # bias is indexed by OUTPUT position: computed block j lands at
         # oidx[j], so its bias tile is read from there too
         in_specs.append(
-            pl.BlockSpec((1, bn), lambda i, j, k, oi: (0, oi[j])))
+            pl.BlockSpec((1, bn), lambda i, j, k, oi, *_: (0, oi[j])))
         operands.append(_pad_to(bias.reshape(1, N), bn, 1))
+    prefetch = [jnp.asarray(oidx)]
+    if stacked:
+        # clamped like the dynamic slice it replaces
+        prefetch.append(jnp.clip(jnp.reshape(layer, (1,)).astype(jnp.int32),
+                                 0, wq.shape[0] - 1))
     gridspec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=len(prefetch),
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k, oi: (i, oi[j])),
+        out_specs=pl.BlockSpec((bm, bn),
+                               lambda i, j, k, oi, *_: (i, oi[j])),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32),
                         pltpu.VMEM((bm, 1), jnp.float32)],
     )
     out = pl.pallas_call(
-        functools.partial(_kernel_fused, nk=grid[2], qmax=qmax,
-                          transpose_w=transpose, activation=activation,
-                          has_bias=has_bias),
+        functools.partial(_kernel_fused_stacked if stacked else _kernel_fused,
+                          nk=grid[2], qmax=qmax, transpose_w=transpose,
+                          activation=activation, has_bias=has_bias),
         grid_spec=gridspec,
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
         interpret=interpret,
-    )(jnp.asarray(oidx), *operands)
+    )(*prefetch, *operands)
     if Mp == M and Np == N:
         return out                       # aligned: no slice round-trip
     return out[:M, :N]

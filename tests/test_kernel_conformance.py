@@ -6,6 +6,8 @@ deselect it from tier-1).  Property-based sweeps use the optional-hypothesis
 shim (skip cleanly when hypothesis is absent); deterministic edge-case
 sweeps run regardless.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -356,6 +358,53 @@ def test_fused_dtypes(dtype):
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                rtol=tol, atol=tol)
+
+
+def _pads_stack(fn, *args) -> bool:
+    """Whether tracing ``fn`` pads a rank-3 (stacked) array, nested jaxprs
+    included: the printed jaxpr names each pad's result type."""
+    text = str(jax.make_jaxpr(fn)(*args))
+    return re.search(r"\w+\[\d+,\d+,\d+\] = pad\[", text) is not None
+
+
+STACKED_CASES = [(R, M, K, N, tr, act, shuffle)
+                 for R in (1, 3) for M in (1, 4, 16, 130)
+                 for K, N in ((256, 384),)
+                 for tr in (False, True) for act in ("none", "silu")
+                 for shuffle in (False, True)]
+# K % bk != 0: the layer is sliced out and padded, never the whole stack
+STACKED_CASES += [(3, M, 200, 384, tr, "silu", shuffle)
+                  for M in (4, 130) for tr in (False, True)
+                  for shuffle in (False, True)]
+
+
+@pytest.mark.parametrize("R,M,K,N,transpose,act,shuffle", STACKED_CASES)
+def test_fused_stacked_bank_bitwise(R, M, K, N, transpose, act, shuffle):
+    """The kernel reading layer ``r`` of a stacked (R, K, N) bank in place
+    is bitwise the kernel on the sliced ``wq[r]``, at every ``r``."""
+    from repro.core.photonic import a8_scale
+    from repro.core.prepared import quantize_weight, quantize_weight_t
+    from repro.kernels.photonic_mvm import (photonic_mvm_fused,
+                                            reads_stack_in_place)
+    k1, k2 = jax.random.split(jax.random.PRNGKey(R * 1000 + M + K))
+    x = jax.random.normal(k1, (M, K), jnp.float32)
+    w = jax.random.normal(k2, (R, N, K) if transpose else (R, K, N))
+    wq, ws = quantize_weight_t(w) if transpose else quantize_weight(w)
+    xs = a8_scale(x)
+    block = 128
+    perm = (2, 0, 1) if shuffle else None
+    kw = dict(bm=ops.round_up(min(M, 128), 8), bk=128, bn=128,
+              transpose=transpose, activation=act, block_perm=perm,
+              block=block if shuffle else 0, interpret=True)
+    assert reads_stack_in_place(K, N, 128, 128) == (K % 128 == 0)
+    for r in range(R):
+        layer = jnp.int32(r)
+        got = photonic_mvm_fused(x, wq, xs, ws, layer=layer, **kw)
+        want = photonic_mvm_fused(x, wq[r], xs, ws[r], **kw)
+        assert np.array_equal(np.asarray(got), np.asarray(want)), r
+    fn = lambda x, wq, ws, layer: photonic_mvm_fused(x, wq, xs, ws,
+                                                     layer=layer, **kw)
+    assert not _pads_stack(fn, x, wq, ws, jnp.int32(0))
 
 
 @given(m=st.integers(1, 80), k=st.integers(1, 80), n=st.integers(1, 80),
