@@ -1,16 +1,21 @@
 """One cell of the benchmark: its entry in ``BENCHMARK.json``, its model
-configuration file and its traffic file, all found by name."""
+configuration file, the configuration's family, its traffic file and its
+check limits, all found by name."""
 from __future__ import annotations
 
 import dataclasses
+import importlib.util
 import json
+import re
+import sys
 from pathlib import Path
+from types import ModuleType
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
-# a configuration file's MLP activation (the published config.json's
-# ``hidden_act``: a gated MLP whose gate takes it) -> the program's mlp_act
-MLP_ACT = {"silu": "swiglu"}
+FAMILIES = HERE / "families"
+# the family of a configuration file that states no published model_type
+DEFAULT_FAMILY = "llama"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -18,6 +23,7 @@ class Cell:
     name: str
     chips: int
     conf: dict           # configs/<config>.json
+    family: ModuleType   # families/<model_type>.py
     traffic: dict        # traffic/<traffic>.json
     limits: dict         # checks/<workload>.json
     end_to_end: list     # BENCHMARK.json metrics this cell reports
@@ -32,6 +38,7 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
         raise KeyError(f"no workload {workload!r} in BENCHMARK.json")
     cfile = {c["name"]: c for c in bench["configs"]}[entry["config"]]["file"]
     conf = json.loads((root / cfile).read_text())
+    family = load_family(conf.get("model_type", DEFAULT_FAMILY))
     traffic = json.loads(
         (HERE / "traffic" / f"{entry['traffic']}.json").read_text())
     limits = json.loads((HERE / "checks" / f"{workload}.json").read_text())
@@ -43,40 +50,36 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
     layer = [m for m in bench["per_layer"]
              if mine(m) and m["moves"] in names]
     return Cell(name=workload, chips=int(entry["chips"]), conf=conf,
-                traffic=traffic, limits=limits, end_to_end=e2e,
+                family=family, traffic=traffic, limits=limits, end_to_end=e2e,
                 per_layer=layer)
 
 
-def model_config(conf: dict):
-    """The program's ModelConfig for a configuration file.  The file is the
-    truth: every size is taken from it, and a feature the program cannot run
-    as the file states is an error, not a silent substitution."""
-    from repro.configs import get_arch
-    from repro.core.prm import ReuseConfig
+def load_family(model_type: str) -> ModuleType:
+    """``families/<model_type>.py``, loaded by path once per process.  A
+    family file gives:
 
-    base = get_arch(conf["architecture"])
-    act = MLP_ACT.get(conf["hidden_act"])
-    if base.mlp_act != act or base.norm != conf["norm"]:
-        raise ValueError(f"{conf['name']}: the program's {base.name} runs "
-                         f"{base.mlp_act}/{base.norm}, the file states "
-                         f"{conf['hidden_act']}/{conf['norm']}")
-    if conf.get("partial_rotary_factor", 1.0) != 1.0:
-        raise ValueError("the program applies RoPE to the whole head")
-    if conf.get("tie_word_embeddings"):
-        raise ValueError("the program keeps its own unembedding")
-    reuse = None
-    if conf.get("reuse"):
-        r = conf["reuse"]
-        reuse = ReuseConfig(granularity="block", num_basic=r["num_basic"],
-                            reuse_times=r["reuse_times"],
-                            transforms=tuple(r["transforms"]),
-                            shuffle_groups=r["shuffle_groups"],
-                            shuffle_block=0)
-    return dataclasses.replace(
-        base, name=conf["name"], num_layers=conf["num_hidden_layers"],
-        d_model=conf["hidden_size"], num_heads=conf["num_attention_heads"],
-        num_kv_heads=conf["num_key_value_heads"], head_dim=conf["head_dim"],
-        d_ff=conf["intermediate_size"], vocab_size=conf["vocab_size"],
-        padded_vocab=0, rope_theta=float(conf["rope_theta"]),
-        norm_eps=float(conf["norm_eps"]), compute_dtype=conf["dtype"],
-        param_dtype=conf["dtype"], execution=conf["execution"], reuse=reuse)
+    - ``program_config(conf)``: the program's ModelConfig, every size from
+      the file; raises on anything the program cannot run as it states;
+    - ``logits(conf, seed, seqs, rows, bits=None, shape=None)``: the plain
+      float32 reference's logits of ``seqs`` at ``rows`` (``reference.py``
+      holds the shared pieces); ``bits`` is the control;
+    - ``token_matmuls(conf)``: (K, N) of every W8A8 matmul one token passes
+      through, in order, ``workcount.unembedding(conf)`` last;
+    - ``attention_call(q_len, q_offset, conf, peaks)``: one logical layer's
+      attention as a ``workcount.Work``.
+
+    Raises FileNotFoundError, naming the file, for a model type that has
+    none."""
+    if not re.fullmatch(r"[A-Za-z0-9_]+", model_type):
+        raise ValueError(f"model_type {model_type!r} is not a family name")
+    path = FAMILIES / f"{model_type}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"model_type {model_type!r} has no family "
+                                f"file: {path} does not exist")
+    name = f"family_{model_type}"
+    if name not in sys.modules:
+        spec = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        sys.modules[name] = mod
+    return sys.modules[name]

@@ -1,9 +1,10 @@
 """What decides ``correct``: served tokens against the plain reference.
 
 After the window, a sample of the finished requests is run through the
-reference once, prompt and served tokens together: the longest, and one
-request served in each slot of the decode batch, drawn from the seed, so
-that a fault in any part of the batch reaches the comparison.  At each
+plain reference of the configuration's family (``families/``) once,
+prompt and served tokens together: the longest, and one request served
+in each slot of the decode batch, drawn from the seed, so that a fault in
+any part of the batch reaches the comparison.  At each
 position that produced a served token, the gap is the reference's best
 logit minus the reference's logit of the token the program served: 0
 where the program chose the reference's argmax, larger the worse its
@@ -17,8 +18,6 @@ the lower precision ranks first.
 from __future__ import annotations
 
 import numpy as np
-
-import reference
 
 
 def sample(done: dict, slot_of: dict, seed: int):
@@ -63,17 +62,18 @@ def gaps(ref_logits: np.ndarray, chosen: np.ndarray) -> np.ndarray:
     return best - ref_logits[np.arange(len(chosen)), chosen]
 
 
-def reference_gaps(conf: dict, seed: int, comps, bits=None, shape=None):
+def reference_gaps(family, conf: dict, seed: int, comps, bits=None,
+                   shape=None):
     """(gaps of the served tokens, gaps of the control's choices or None)
-    at every served position of ``comps``; ``shape`` is the reference's
-    fixed (sequences, length)."""
+    at every served position of ``comps``, by ``family.logits``; ``shape``
+    is the reference's fixed (sequences, length)."""
     seqs, rows = seqs_of(comps), [positions(c) for c in comps]
-    ref = np.asarray(reference.logits(conf, seed, seqs, rows, shape=shape),
+    ref = np.asarray(family.logits(conf, seed, seqs, rows, shape=shape),
                      np.float32)
     program = gaps(ref, served(comps))
     control = None
     if bits:
-        low = np.asarray(reference.logits(conf, seed, seqs, rows, bits=bits,
-                                          shape=shape))
+        low = np.asarray(family.logits(conf, seed, seqs, rows, bits=bits,
+                                       shape=shape))
         control = gaps(ref, low.argmax(axis=-1))
     return program, control

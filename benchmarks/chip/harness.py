@@ -21,7 +21,7 @@ from pathlib import Path
 
 import numpy as np
 
-from cell import ROOT, Cell, model_config
+from cell import ROOT, Cell
 from traffic import Traffic, percentile
 
 # After the window closes, requests due in it are stepped until each has
@@ -246,7 +246,7 @@ def build(cell: Cell, seed: int, traffic: Traffic):
     from repro.models import transformer as tfm
     import weights
 
-    cfg = model_config(cell.conf)
+    cfg = cell.family.program_config(cell.conf)
     t = now()
     params = weights.make_params(tfm.abstract_params(cfg), seed,
                                  jax.numpy.dtype(cfg.compute_dtype))
@@ -406,9 +406,10 @@ def prefill_calls(steps, rec: Recorder, serve: dict) -> None:
 @dataclasses.dataclass
 class LayerContext:
     """Everything a per-layer metric reader (``metrics/<name>.py``) may
-    read: the traced steps, the trace's reduction, the configuration, the
-    cell's serving parameters and the peaks."""
+    read: the traced steps, the trace's reduction, the configuration and
+    its family, the cell's serving parameters and the peaks."""
     conf: dict
+    family: object            # families/<model_type>.py: the work count
     serve: dict
     peaks: object
     steps: list               # StepRec of the traced window, with .prefill
@@ -421,8 +422,9 @@ def layer_context(cell: Cell, driver: Driver, summary, tt0, tt1,
     rec = driver.rec
     prefill_calls(driver.steps, rec, cell.traffic["serve"])
     return LayerContext(
-        conf=cell.conf, serve=cell.traffic["serve"], peaks=peaks,
-        steps=[s for s in driver.steps if s.traced], trace=summary,
+        conf=cell.conf, family=cell.family, serve=cell.traffic["serve"],
+        peaks=peaks, steps=[s for s in driver.steps if s.traced],
+        trace=summary,
         decode_ctx=[c for t, c in rec.decode_ctx if tt0 <= t <= tt1])
 
 
@@ -473,8 +475,8 @@ def correctness(cell: Cell, seed: int, rec: Recorder,
         # one reference shape per cell: a request from every slot and the
         # longest, at the slot budget's length
         shape = (serve["capacity"] + 1, serve["max_len"])
-        g, ctl = check.reference_gaps(cell.conf, seed, comps, control_bits,
-                                      shape)
+        g, ctl = check.reference_gaps(cell.family, cell.conf, seed, comps,
+                                      control_bits, shape)
         log(f"reference: {len(comps)} requests, {len(g)} served tokens, "
             f"{now() - t!r} s")
         gap = float(np.max(g))

@@ -2,7 +2,8 @@
 the least time of every weight matmul of the traced decode steps (each
 call: 2*M*K*N ops at the int8 peak, or int8 weight plus bf16 activation
 bytes at HBM bandwidth, whichever is larger; M the slot capacity) over
-the kernel's device time there.  Moves output_tok_s."""
+the kernel's device time there.  The matmuls are the configuration's
+family's (``token_matmuls``).  Moves output_tok_s."""
 import workcount
 
 
@@ -11,5 +12,6 @@ def read(ctx):
     steps = sum(s.decode_steps for s in ctx.steps)
     if not secs or not steps:
         return None
-    one = workcount.mvm_calls(ctx.serve["capacity"], ctx.conf, ctx.peaks)
+    one = workcount.mvm_calls(ctx.serve["capacity"], ctx.family, ctx.conf,
+                              ctx.peaks)
     return 100.0 * steps * one.min_seconds / secs

@@ -1,4 +1,5 @@
-"""The plain reference against the program on the CPU at a tiny size.
+"""The llama family's plain reference against the program on the CPU at a
+tiny size.
 
 In float32 with XLA execution the program computes the reference's
 mathematics in another order: the logits agree to float32 rounding.  The
@@ -13,10 +14,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import reference
 import weights
-from cell import model_config
+from cell import load_family
 from tiny import tiny_conf
+
+llama = load_family("llama")
 
 # float32 XLA vs the float32 reference: summation order only.
 F32_REL = 1e-4
@@ -25,7 +27,7 @@ F32_REL = 1e-4
 def _program(conf, seed, dtype):
     from repro.api import Program
     from repro.models import transformer as tfm
-    cfg = model_config(conf)
+    cfg = llama.program_config(conf)
     cfg = dataclasses.replace(cfg, compute_dtype=dtype, param_dtype=dtype)
     params = weights.make_params(tfm.abstract_params(cfg), seed,
                                  jnp.bfloat16)
@@ -43,7 +45,7 @@ def test_float32_xla_program_equals_reference(name):
     with jax.default_matmul_precision("highest"):
         got, _ = prog.prefill({"tokens": jnp.asarray(prompt[None])}, 37)
     got = np.asarray(got[0, :conf["vocab_size"]], np.float64)
-    want = np.asarray(reference.logits(conf, seed, [prompt], [[36]])[0],
+    want = np.asarray(llama.logits(conf, seed, [prompt], [[36]])[0],
                       np.float64)
     rel = np.linalg.norm(got - want) / np.linalg.norm(want)
     assert rel < F32_REL, rel
@@ -54,7 +56,7 @@ def test_weights_match_the_programs_tree():
     into the program's tree."""
     from repro.models import transformer as tfm
     conf = tiny_conf("deepseek-7b-rb")
-    cfg = model_config(conf)
+    cfg = llama.program_config(conf)
     params = weights.make_params(tfm.abstract_params(cfg), 5, jnp.bfloat16)
     stacked = params["segments"]["main"]["l0"]["ffn"]["w_down"]
     base = weights.base_key(5)

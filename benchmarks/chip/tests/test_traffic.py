@@ -1,4 +1,5 @@
-"""The load generator: every seed serves the same sizes in another order."""
+"""The load generator: every seed serves the same sizes, shuffled or in one
+fixed order."""
 from __future__ import annotations
 
 import json
@@ -23,8 +24,9 @@ def open_mix():
     return spec
 
 
-def test_every_seed_serves_the_same_sizes():
-    spec = mix("chat")
+@pytest.mark.parametrize("order", ["shuffle", "fixed"])
+def test_every_seed_serves_the_same_sizes(order):
+    spec = dict(mix("chat"), order=order)
     n = spec["population"]
     runs = []
     for seed in (1, 2 ** 40 + 7):
@@ -32,8 +34,27 @@ def test_every_seed_serves_the_same_sizes():
         runs.append([(len(p), m) for p, m in
                      (t.next_request() for _ in range(2 * n))])
     assert sorted(runs[0]) == sorted(runs[1])
-    assert runs[0] != runs[1]
+    assert (runs[0] == runs[1]) == (order == "fixed")
     assert sorted(runs[0][:n]) == sorted(runs[0][n:])
+
+
+def test_fixed_serves_one_cycle_and_seeds_only_the_tokens():
+    spec = mix("chat")
+    assert spec["order"] == "fixed"
+    n = spec["population"]
+    a, b = Traffic(spec, 1, 256000), Traffic(spec, 2 ** 40 + 7, 256000)
+    ra = [a.next_request() for _ in range(2 * n)]
+    rb = [b.next_request() for _ in range(2 * n)]
+    sizes = [(len(p), m) for p, m in ra]
+    assert sizes == [(len(p), m) for p, m in rb]
+    assert sizes[:n] == sizes[n:]
+    assert not any(np.array_equal(pa, pb)
+                   for (pa, _), (pb, _) in zip(ra, rb))
+
+
+def test_unknown_order_is_refused():
+    with pytest.raises(ValueError, match="order"):
+        Traffic(dict(mix("chat"), order="sorted"), 1, 1000)
 
 
 @pytest.mark.parametrize("name", ["chat", "chat-4slots"])

@@ -1,5 +1,5 @@
-"""Operation and byte counts against numbers worked out by hand for
-deepseek-7b, and the peak table."""
+"""Operation and byte counts of the llama family against numbers worked out
+by hand for deepseek-7b, and the peak table."""
 from __future__ import annotations
 
 import json
@@ -8,10 +8,12 @@ from pathlib import Path
 import pytest
 
 import workcount
+from cell import load_family
 from peaks import peaks_for
 
 CONFIGS = Path(__file__).resolve().parents[1] / "configs"
 V5E = peaks_for("TPU v5 lite")
+llama = load_family("llama")
 
 
 def conf(name):
@@ -20,17 +22,18 @@ def conf(name):
 
 def test_deepseek_layer_params():
     # MHA attention 4 * 4096^2, SwiGLU 3 * 4096 * 11008
-    assert workcount.params_per_layer(conf("deepseek-7b")) == 202_375_168
+    layer = llama.layer_matmuls(conf("deepseek-7b"))
+    assert sum(k * n for _, k, n in layer) == 202_375_168
 
 
 def test_rb_counts_every_logical_pass():
     dense, rb = conf("deepseek-7b"), conf("deepseek-7b-rb")
     unembed = 4096 * 102400
-    assert workcount.matmul_params_per_token(dense) == (
-        8 * 202_375_168 + unembed)
+    def per_token(c):
+        return sum(k * n for k, n in llama.token_matmuls(c))
+    assert per_token(dense) == 8 * 202_375_168 + unembed
     # 10 physical blocks, each read by 3 sequential passes: 30 reads
-    assert workcount.matmul_params_per_token(rb) == (
-        30 * 202_375_168 + unembed)
+    assert per_token(rb) == 30 * 202_375_168 + unembed
 
 
 def test_decode_mvm_call_is_bandwidth_bound():
@@ -48,16 +51,18 @@ def test_prefill_mvm_call_is_compute_bound():
 
 def test_causal_attention_chunk():
     assert workcount.causal_pairs(512, 1024) == 512 * 1024 + 512 * 513 // 2
-    w = workcount.attention_call(512, 1024, conf("deepseek-7b"), V5E)
+    w = llama.attention_call(512, 1024, conf("deepseek-7b"), V5E)
     assert w.bf16_flops == 4 * 32 * 128 * 655_616
     assert w.bytes == 2 * 128 * (2 * 512 * 32 + 2 * 1536 * 32)
-    L = workcount.attention_calls(512, 1024, conf("deepseek-7b-rb"), V5E)
+    L = workcount.attention_calls(512, 1024, llama, conf("deepseek-7b-rb"),
+                                  V5E)
     assert L.bf16_flops == 30 * w.bf16_flops
 
 
 def test_ideal_seconds_splits_the_peaks():
     c = conf("deepseek-7b")
-    got = workcount.ideal_seconds(10, 2, 1000, c, V5E)
+    # one query at offset 999 keeps 1000 pairs
+    got = workcount.ideal_seconds(10, 2, [(1, 999)], llama, c, V5E)
     mm = 2 * (10 * 8 * 202_375_168 + 2 * 4096 * 102400)
     att = 4 * 32 * 128 * 1000 * 8
     assert got == pytest.approx(mm / 393e12 + att / 197e12)

@@ -6,7 +6,7 @@ import copy
 import json
 from pathlib import Path
 
-from cell import Cell
+from cell import DEFAULT_FAMILY, Cell, load_family
 
 HERE = Path(__file__).resolve().parents[1]
 
@@ -17,9 +17,12 @@ TINY_SERVE = {"capacity": 4, "max_len": 192, "prefill_chunk": 64,
               "prefill_bucket": 16}
 
 
-def tiny_conf(name: str, layers: int = 2) -> dict:
+def tiny_conf(name: str, layers: int = 2, sizes: dict = TINY_SIZES) -> dict:
+    """The configuration file ``name`` with ``sizes`` (the llama family's
+    widths by default; a family's tests pass its own keys) and ``layers``
+    layers, or two physical blocks under a reuse plan."""
     conf = json.loads((HERE / "configs" / f"{name}.json").read_text())
-    conf.update(TINY_SIZES)
+    conf.update(sizes)
     if conf.get("reuse"):
         conf["reuse"] = dict(conf["reuse"], num_basic=2)
         conf["num_hidden_layers"] = 2 * conf["reuse"]["reuse_times"]
@@ -47,6 +50,7 @@ def tiny_cell(name: str = "deepseek-7b", loop: str = "closed",
     conf = tiny_conf(name)
     conf["execution"] = execution
     return Cell(name=f"tiny.{name}", chips=1, conf=conf,
+                family=load_family(conf.get("model_type", DEFAULT_FAMILY)),
                 traffic=tiny_traffic(loop),
                 limits={"max_logit_gap": {"limit": limit}},
                 end_to_end=copy.deepcopy(END_TO_END), per_layer=[])

@@ -4,9 +4,14 @@
 A mix fixes a *population* of request sizes: ``population`` (prompt,
 output) pairs at evenly spaced quantiles of the two length distributions,
 paired by a fixed permutation.  A run's seed only orders that population
-(one fresh permutation per pass over it) and draws the token ids, so every
-seed serves the same set of sizes and, in an open loop, the same set of
-inter-arrival gaps: what changes between seeds is the order, not the work.
+and draws the token ids, so every seed serves the same set of sizes and,
+in an open loop, the same set of inter-arrival gaps: what changes between
+seeds is the order, not the work.  ``order`` says how: ``shuffle`` (the
+default) draws a fresh permutation for every pass over the population;
+``fixed`` serves one permutation, the same for every seed, over and over,
+so the seed draws only the token ids.  In a closed loop the order is part
+of the work: it decides which long prompts stage their prefill together,
+and so where a tail percentile of the first token lies.
 
 Distributions: ``lognormal`` (``median``, ``sigma``), ``loguniform`` and
 ``uniform``, each clipped to ``[min, max]``.  Loops: ``closed`` (``clients``
@@ -72,6 +77,11 @@ class Traffic:
         self._gap_rng = np.random.default_rng([seed, 1])
         self._order: list[int] = []
         self._gaps: list[float] = []
+        self.order = spec.get("order", "shuffle")
+        if self.order not in ("shuffle", "fixed"):
+            raise ValueError(f"unknown order {self.order!r}")
+        self._cycle = list(np.random.default_rng(1).permutation(len(self.pop)))
+        self._pos = 0
         self.gap_pop = (gap_population(spec) if spec["loop"] == "open"
                         else None)
 
@@ -81,9 +91,14 @@ class Traffic:
 
     def next_request(self) -> tuple[np.ndarray, int]:
         """(prompt token ids, max_new) of the next request."""
-        if not self._order:
-            self._order = list(self._req_rng.permutation(len(self.pop)))
-        plen, max_new = self.pop[self._order.pop()]
+        if self.order == "fixed":
+            i = self._cycle[self._pos % len(self._cycle)]
+            self._pos += 1
+        else:
+            if not self._order:
+                self._order = list(self._req_rng.permutation(len(self.pop)))
+            i = self._order.pop()
+        plen, max_new = self.pop[i]
         prompt = self._req_rng.integers(1, self.vocab, plen, dtype=np.int32)
         return prompt, max_new
 

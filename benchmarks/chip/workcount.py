@@ -1,5 +1,11 @@
 """Operations and bytes of the model's work, from the configuration's shapes.
 
+The configuration's family (``families/<model_type>.py``) says what one
+token passes through: ``token_matmuls(conf)``, the (K, N) of every weight
+matmul in order, the unembedding last, and ``attention_call(q_len,
+q_offset, conf, peaks)``, one logical layer's attention as a ``Work``.
+This module prices the matmuls and sums both into the readers' totals.
+
 Everything here is the *logical* work: a matmul is 2*M*K*N operations on
 its shapes, and attention is counted over the (query, key) pairs causality
 keeps, whatever kernel implements either.  Under a PRM reuse plan every
@@ -19,15 +25,6 @@ ACT_BYTES = 2          # bf16 activations, outputs, K/V
 WEIGHT_BYTES = 1       # int8 bank
 
 
-def layer_matmuls(conf: dict) -> list[tuple[str, int, int]]:
-    """(name, K, N) of each weight matmul of one logical layer."""
-    d, ff = conf["hidden_size"], conf["intermediate_size"]
-    q = conf["num_attention_heads"] * conf["head_dim"]
-    kv = conf["num_key_value_heads"] * conf["head_dim"]
-    return [("wq", d, q), ("wk", d, kv), ("wv", d, kv), ("wo", q, d),
-            ("w_gate", d, ff), ("w_up", d, ff), ("w_down", ff, d)]
-
-
 def padded_vocab(conf: dict) -> int:
     return -(-conf["vocab_size"] // 256) * 256
 
@@ -36,20 +33,9 @@ def logical_layers(conf: dict) -> int:
     return conf["num_hidden_layers"]
 
 
-def params_per_layer(conf: dict) -> int:
-    return sum(k * n for _, k, n in layer_matmuls(conf))
-
-
-def token_matmuls(conf: dict) -> list[tuple[int, int]]:
-    """(K, N) of every weight matmul one token passes through, in order:
-    every logical layer's, then the unembedding."""
-    one = [(k, n) for _, k, n in layer_matmuls(conf)]
-    return (one * logical_layers(conf)
-            + [(conf["hidden_size"], padded_vocab(conf))])
-
-
-def matmul_params_per_token(conf: dict) -> int:
-    return sum(k * n for k, n in token_matmuls(conf))
+def unembedding(conf: dict) -> tuple[int, int]:
+    """(K, N) of the unembedding, the last matmul every token passes."""
+    return conf["hidden_size"], padded_vocab(conf)
 
 
 @dataclasses.dataclass
@@ -79,11 +65,11 @@ def mvm_call(M: int, K: int, N: int, peaks) -> Work:
                                 nbytes / peaks.hbm_bytes))
 
 
-def mvm_calls(M: int, conf: dict, peaks) -> Work:
+def mvm_calls(M: int, family, conf: dict, peaks) -> Work:
     """Every weight matmul of one forward pass of M rows through all
-    logical layers and the unembedding."""
+    logical layers and the unembedding (the family's ``token_matmuls``)."""
     w = Work()
-    for k, n in token_matmuls(conf):
+    for k, n in family.token_matmuls(conf):
         w.add(mvm_call(M, k, n, peaks))
     return w
 
@@ -94,37 +80,31 @@ def causal_pairs(q_len: int, q_offset: int) -> int:
     return q_len * q_offset + q_len * (q_len + 1) // 2
 
 
-def attention_call(q_len: int, q_offset: int, conf: dict, peaks) -> Work:
-    """One layer's causal attention of q_len queries at q_offset: 4 * H *
-    head_dim FLOPs per kept pair (QK^T and PV); bytes: q and o of the
-    queries, k and v of the keys up to the last query."""
-    H, KV = conf["num_attention_heads"], conf["num_key_value_heads"]
-    hd = conf["head_dim"]
-    flops = 4.0 * H * hd * causal_pairs(q_len, q_offset)
-    nbytes = ACT_BYTES * hd * (2 * q_len * H + 2 * (q_offset + q_len) * KV)
-    return Work(bf16_flops=flops, bytes=nbytes,
-                min_seconds=max(flops / peaks.bf16_flops,
-                                nbytes / peaks.hbm_bytes))
-
-
-def attention_calls(q_len: int, q_offset: int, conf: dict, peaks) -> Work:
-    """The same attention call in every logical layer."""
-    one = attention_call(q_len, q_offset, conf, peaks)
+def attention_calls(q_len: int, q_offset: int, family, conf: dict,
+                    peaks) -> Work:
+    """The family's attention call of q_len queries at q_offset in every
+    logical layer."""
+    one = family.attention_call(q_len, q_offset, conf, peaks)
     L = logical_layers(conf)
     return Work(bf16_flops=one.bf16_flops * L, bytes=one.bytes * L,
                 min_seconds=one.min_seconds * L)
 
 
-def ideal_seconds(layer_tokens: int, unembed_rows: int, attn_pairs: int,
-                  conf: dict, peaks) -> float:
+def ideal_seconds(layer_tokens: int, unembed_rows: int, attention,
+                  family, conf: dict, peaks) -> float:
     """Least time of model work: ``layer_tokens`` token passes through
     every logical layer's weight matmuls, ``unembed_rows`` rows through the
-    unembedding, and attention keeping ``attn_pairs`` (query, key) pairs
-    per layer in all.  Weight matmuls at the int8 peak, attention at the
-    bf16 peak; compute only (the whole step's share of the chip's peak)."""
-    layer = sum(k * n for _, k, n in layer_matmuls(conf))
-    mm = 2.0 * (layer_tokens * layer * logical_layers(conf)
-                + unembed_rows * conf["hidden_size"] * padded_vocab(conf))
-    att = (4.0 * conf["num_attention_heads"] * conf["head_dim"] * attn_pairs
-           * logical_layers(conf))
+    unembedding, and one attention call per ``(q_len, q_offset)`` in
+    ``attention`` in every logical layer.  Weight matmuls at the int8
+    peak, attention at the bf16 peak; compute only (the whole step's share
+    of the chip's peak)."""
+    *layers, last = family.token_matmuls(conf)
+    if last != unembedding(conf):
+        raise ValueError(f"token_matmuls ends in {last}, not the "
+                         f"unembedding {unembedding(conf)}")
+    k, n = last
+    mm = 2.0 * (layer_tokens * sum(a * b for a, b in layers)
+                + unembed_rows * k * n)
+    att = sum(attention_calls(q, o, family, conf, peaks).bf16_flops
+              for q, o in attention)
     return mm / peaks.int8_ops + att / peaks.bf16_flops
