@@ -274,12 +274,16 @@ def _decode_cells(donate: bool):
     def decode_sample_cell(bank, tokens, caches, pos, key, temperature, *,
                            cfg: ModelConfig, backend, greedy: bool):
         """Fused decode + sample: one jitted computation per token (the
-        sampler never round-trips logits through the host)."""
+        sampler never round-trips logits through the host).  Returns
+        (tokens, caches, routing): on an MoE model routing is every MoE
+        layer's expert ids of every row (MoE layers, B, top_k), else
+        None."""
         TRACE_COUNTS["decode_sample"] += 1
-        logits, caches, _ = tfm.forward(
+        logits, caches, aux = tfm.forward(
             bank, cfg, {"tokens": tokens}, mode="decode", caches=caches,
-            pos=pos, execution=backend,
+            pos=pos, execution=backend, record_routing=True,
             act_pspec=_serve_act_pspec(backend, tokens.shape[0]))
+        routing = aux["route"] if isinstance(aux, dict) else None
         logits = _mask_padded(logits[:, 0, :].astype(jnp.float32),
                               cfg.vocab_size)
         if greedy:
@@ -287,7 +291,7 @@ def _decode_cells(donate: bool):
         else:
             tok = jax.random.categorical(key, logits / temperature,
                                          axis=-1).astype(jnp.int32)
-        return tok, caches
+        return tok, caches, routing
 
     return decode_cell, decode_sample_cell
 
@@ -479,8 +483,10 @@ class Program:
                     backend=self.backend)
 
     def decode_sample(self, tokens, caches, pos, key=None,
-                      temperature: float = 0.0):
-        """Fused decode + sample step.  Returns (token_ids (B,), caches)."""
+                      temperature: float = 0.0, routing: bool = False):
+        """Fused decode + sample step.  Returns (token_ids (B,), caches),
+        and with ``routing`` the step's expert ids (MoE layers, B, top_k)
+        as a third item (None on a model without experts)."""
         if temperature > 0.0 and key is None:
             raise ValueError("decode_sample(temperature>0) needs a PRNG key")
         if key is None:
@@ -488,10 +494,11 @@ class Program:
         if metrics_lib.enabled():
             metrics_lib.counter("program.steps", kind="decode_sample").inc()
         _, cell = _decode_cells(_donate_caches())
-        return cell(
+        tok, caches, route = cell(
             self.bank, tokens, caches, pos, key,
             jnp.float32(max(temperature, 1e-6)), cfg=self.cfg,
             backend=self.backend, greedy=temperature <= 0.0)
+        return (tok, caches, route) if routing else (tok, caches)
 
     def loss(self, batch):
         """Mean next-token cross-entropy of ``batch`` (eval; no gradients).

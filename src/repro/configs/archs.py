@@ -9,7 +9,8 @@ from __future__ import annotations
 import dataclasses
 
 from repro.configs.base import (AudioConfig, MLAConfig, ModelConfig,
-                                MoEConfig, SSMConfig, VisionConfig)
+                                MoEConfig, RopeScaling, SSMConfig,
+                                VisionConfig)
 from repro.core.prm import ReuseConfig
 
 DEFAULT_TRANSFORMS = ("identity", "shuffle", "transpose", "shuffle")
@@ -55,16 +56,22 @@ def granite_moe_1b_a400m() -> ModelConfig:
 
 
 def deepseek_v2_lite_16b() -> ModelConfig:
-    """MLA kv_lora=512, 2 shared + 64 routed top-6 [arXiv:2405.04434]."""
+    """MLA kv_lora=512 with the latent norm and YaRN RoPE, 2 shared + 64
+    routed experts, raw top-6 softmax gates [arXiv:2405.04434;
+    hf:deepseek-ai/DeepSeek-V2-Lite config.json]."""
     return ModelConfig(
         name="deepseek-v2-lite-16b", family="moe", num_layers=27,
         d_model=2048, num_heads=16, num_kv_heads=16, d_ff=1408,
-        vocab_size=102400, head_dim=192,
+        vocab_size=102400, head_dim=192, norm_eps=1e-6,
+        rope_scaling=RopeScaling(factor=40.0, original_max_position=4096,
+                                 beta_fast=32.0, beta_slow=1.0,
+                                 mscale=0.707, mscale_all_dim=0.707),
         mla=MLAConfig(kv_lora_rank=512, qk_nope_dim=128, qk_rope_dim=64,
                       v_head_dim=128),
         moe=MoEConfig(num_experts=64, top_k=6, d_ff_expert=1408,
                       num_shared=2, d_ff_shared=2816,
-                      first_dense=1, first_dense_d_ff=10944))
+                      first_dense=1, first_dense_d_ff=10944,
+                      norm_topk_prob=False))
 
 
 def minitron_4b() -> ModelConfig:
@@ -189,7 +196,8 @@ def smoke_variant(name: str) -> ModelConfig:
                                 qk_rope_dim=4, v_head_dim=8),
                   moe=MoEConfig(num_experts=4, top_k=2, d_ff_expert=32,
                                 num_shared=1, d_ff_shared=32, first_dense=1,
-                                first_dense_d_ff=96, capacity_factor=4.0))
+                                first_dense_d_ff=96, capacity_factor=4.0,
+                                norm_topk_prob=cfg.moe.norm_topk_prob))
     elif cfg.family == "moe":
         kw.update(num_layers=4,
                   moe=MoEConfig(num_experts=4, top_k=2, d_ff_expert=32,

@@ -18,12 +18,30 @@ class MoEConfig:
     moe_offset: int = 0
     first_dense: int = 0         # first k layers use a dense FFN (DeepSeek-V2)
     first_dense_d_ff: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25  # training only: serving routes dropless
     group_tokens: int = 1024     # routing-group size (GShard G dimension)
+    norm_topk_prob: bool = True  # renormalise the top-k gates to sum to 1;
+                                 # False keeps the raw softmax gates
+                                 # (DeepSeek-V2)
     router_dtype: str = "float32"
     num_basic_experts: int = 0   # PRM across experts: E experts blended
                                  # from this many basic experts via OBU
                                  # shuffles (0 = off; beyond-paper)
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN RoPE scaling (arXiv:2309.00071), as DeepSeek-V2 configures it:
+    frequencies blend interpolation by ``factor`` and extrapolation over the
+    ramp that ``beta_fast``/``beta_slow`` set at ``original_max_position``;
+    ``mscale``/``mscale_all_dim`` set the cos/sin factor and (MLA) the
+    softmax scale."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -71,6 +89,7 @@ class ModelConfig:
     vocab_size: int
     head_dim: Optional[int] = None
     rope_theta: float = 1e4
+    rope_scaling: Optional[RopeScaling] = None   # None: plain RoPE
     norm: str = "rms"              # rms | layer
     norm_eps: float = 1e-5
     mlp_act: str = "swiglu"        # swiglu | gelu

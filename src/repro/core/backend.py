@@ -293,7 +293,8 @@ class Backend:
         return (self.is_photonic and self.flash and not self.mesh_active
                 and q_len >= self.flash_min_seq)
 
-    def attention(self, q, k, v, *, causal: bool = True, q_offset=None):
+    def attention(self, q, k, v, *, causal: bool = True, q_offset=None,
+                  scale=None):
         """Sequence attention under the backend seam — the prefill analogue
         of ``dot``.
 
@@ -308,9 +309,10 @@ class Backend:
         in ``models/attention.py``.  ``q_offset`` (python int or traced
         scalar) places query row i at absolute position q_offset + i so a
         chunked prefill against a partially filled KV cache masks exactly
-        like the monolithic pass.  Being a ``Backend`` method, the routing
-        decision (``flash``/``flash_min_seq``) is part of the static
-        jit-cell key like every other field."""
+        like the monolithic pass.  ``scale`` (a python float) multiplies
+        the scores; None is 1/sqrt(hd).  Being a ``Backend`` method, the
+        routing decision (``flash``/``flash_min_seq``) is part of the
+        static jit-cell key like every other field."""
         B, Sq, H, _ = q.shape
         L, hd_v = k.shape[1], v.shape[-1]
         if self.use_flash(Sq):
@@ -318,11 +320,11 @@ class Backend:
             _metrics.record_kernel_call("flash_attn", bq, bk_, hd_v)
             with jax.named_scope(f"photonic.flash_attn.{bq}x{bk_}"):
                 o = ops.flash_attention(q, k, v, causal=causal,
-                                        q_offset=q_offset)
+                                        q_offset=q_offset, scale=scale)
             return o.reshape(B, Sq, H * hd_v)
         from repro.models import attention as _attn   # lazy: models -> core
         return _attn.attend_seq_xla(q, k, v, causal=causal,
-                                    q_offset=q_offset)
+                                    q_offset=q_offset, scale=scale)
 
     # ------------------------------------------------------------- matmuls
     def dot(self, x, w, *, transpose: bool = False, bias=None,

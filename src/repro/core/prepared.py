@@ -70,7 +70,10 @@ QMAX = 127.0
 #   table  — embedding gather needs the fp table (the tied lm-head matmul
 #            keeps the legacy in-kernel quantize path);
 #   router — MoE routing is fp32 + top-k on every backend;
-#   w_ukv  — MLA decode absorbs it into the latent einsums.
+#   w_ukv  — MLA's latent up-projection has one treatment on every path:
+#            decode absorbs it into the latent einsums and prefill
+#            up-projects the cached latents, both as compute-dtype einsums
+#            (``models/attention._up_project``), never as a W8A8 bank.
 MATMUL_LEAVES = frozenset({
     "wq", "wk", "wv", "wo",                      # attention projections
     "w_gate", "w_up", "w_down",                  # MLPs + MoE expert banks
@@ -220,7 +223,8 @@ class BankLayer:
     dynamic slice of the stack, ``x[i]`` — so every consumer that does not
     know it gets exactly that slice; only the fused single-device photonic
     dot (``Backend.dot_prepared``) passes ``stack`` and ``index`` to the
-    kernel, which reads the layer's tiles in place."""
+    kernel, which reads the layer's tiles in place.  On an expert bank
+    (R, E, K, N), ``x[e]`` is expert e's view of the same kind."""
 
     stack: PreparedTensor    # (R, ...) stacked bank
     index: jax.Array         # traced int32 scalar, the layer
@@ -262,6 +266,13 @@ class BankLayer:
         return self
 
     def __getitem__(self, idx):
+        if isinstance(idx, int) and self.stack.ndim > 3:
+            # expert ``idx`` of a stacked (R, E, K, N) bank: a view of the
+            # same stack as (R*E, K, N), so it too is read in place
+            n = self.stack.shape[1]
+            flat = jax.tree.map(lambda f: f.reshape((-1,) + f.shape[2:]),
+                                self.stack)
+            return BankLayer(flat, self.index * n + idx)
         return self.layer()[idx]
 
 

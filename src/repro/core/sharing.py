@@ -120,7 +120,8 @@ def identity_stack(depth: int, channels: int) -> SharedStack:
 BlockFn = Callable[..., tuple]
 # block_fn(params_r, x, cache_t, aux, *, transpose: bool, reuse_index: int)
 #   -> (x, new_cache_t, aux)     where cache_t may be None; aux is a scalar
-#   accumulator (e.g. MoE load-balance loss) threaded through the scan.
+#   accumulator (e.g. MoE load-balance loss) threaded through the scan, or
+#   a pytree of them (``models.moe.route_record``).
 
 
 def _cache_at(cache_leaf, r, t):
@@ -200,7 +201,8 @@ def run_stack(block_fn: BlockFn, params: Any, x: jax.Array,
     """
     T = shared.reuse_times
     have_cache = cache is not None
-    aux0 = jnp.asarray(aux0, dtype=jnp.float32)
+    if not isinstance(aux0, dict):           # a dict: moe.route_record
+        aux0 = jnp.asarray(aux0, dtype=jnp.float32)
     backend = backend_lib.resolve(backend)
     bpt = shared.block_perm_table
 

@@ -108,15 +108,17 @@ def _kernel(off_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 
 def flash_attention(q, k, v, *, causal=True, q_offset=None, kv_len=None,
-                    bq=None, bk=None, interpret=None):
+                    bq=None, bk=None, interpret=None, scale=None):
     """Blocked attention over flattened heads.
 
     q: (BH_q, Sq, hd); k: (BH_kv, L, hd); v: (BH_kv, L, hd_v) with
     BH_q % BH_kv == 0 (query row b reads kv row b // G).  Returns
     (BH_q, Sq, hd_v).  Ragged Sq / L are padded to the tile here; ``kv_len``
     (default L) masks trailing padded keys; ``q_offset`` shifts the causal
-    mask for chunked prefill.  ``interpret=None`` resolves from the platform
-    (the same check the MVM kernels use) instead of the old hardcoded True.
+    mask for chunked prefill.  ``scale`` (a static python float)
+    multiplies the scores; None is 1/sqrt(hd).  ``interpret=None`` resolves
+    from the platform (the same check the MVM kernels use) instead of the
+    old hardcoded True.
     """
     BHq, Sq, hd = q.shape
     BHkv, L, hdk = k.shape
@@ -142,7 +144,7 @@ def flash_attention(q, k, v, *, causal=True, q_offset=None, kv_len=None,
         k = jnp.pad(k, ((0, 0), (0, L_p - L), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, L_p - L), (0, 0)))
     off = jnp.full((1, 1), 0 if q_offset is None else q_offset, jnp.int32)
-    scale = 1.0 / (hd ** 0.5)
+    scale = 1.0 / (hd ** 0.5) if scale is None else float(scale)
     grid = (BHq, Sq_p // bq, L_p // bk)
     out = pl.pallas_call(
         functools.partial(_kernel, nk=grid[2], bq=bq, bk=bk, scale=scale,

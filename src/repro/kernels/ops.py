@@ -194,21 +194,22 @@ def blend_shuffle(x, bias, block_perm, *, block=128, activation="relu"):
 
 
 def flash_attention(q, k, v, *, causal=True, q_offset=None, bq=None,
-                    bk=None):
+                    bk=None, scale=None):
     """Tensor-shaped flash attention: q (B, Sq, H, hd); k (B, L, KV, hd);
     v (B, L, KV, hd_v) with H % KV == 0 (GQA groups; MLA's hd_v != hd rides
     on the separate v head dim).  Head flattening keeps the (B, S, KV, G)
     ordering of ``_gqa_attend`` so query row b*H + kv*G + g reads kv row
     b*KV + kv inside the kernel.  Returns (B, Sq, H, hd_v).  ``q_offset``
-    shifts the causal mask for chunked prefill; block sizes and interpret
-    default from the platform (``flash_attention.default_blocks``)."""
+    shifts the causal mask for chunked prefill; ``scale`` multiplies the
+    scores (None: 1/sqrt(hd)); block sizes and interpret default from the
+    platform (``flash_attention.default_blocks``)."""
     B, Sq, H, hd = q.shape
     _, L, KV, hdv = v.shape
     qf = q.transpose(0, 2, 1, 3).reshape(B * H, Sq, hd)
     kf = k.transpose(0, 2, 1, 3).reshape(B * KV, L, hd)
     vf = v.transpose(0, 2, 1, 3).reshape(B * KV, L, hdv)
     o = _fa.flash_attention(qf, kf, vf, causal=causal, q_offset=q_offset,
-                            bq=bq, bk=bk)
+                            bq=bq, bk=bk, scale=scale)
     return o.reshape(B, H, Sq, hdv).transpose(0, 2, 1, 3)
 
 

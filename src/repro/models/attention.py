@@ -13,12 +13,15 @@ angles are per-slot in the vector case.  Softmax is always fp32.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import MLAConfig, ModelConfig
 from repro.core.backend import resolve as resolve_backend
-from repro.models.layers import _dense_init, apply_rope, rope_angles
+from repro.models.layers import (_dense_init, apply_norm, apply_rope,
+                                 rope_angles, yarn_mscale)
 
 NEG_INF = -1e30
 
@@ -73,15 +76,19 @@ CHUNKED_ATTN_THRESHOLD = 8192   # use O(S*bq) chunked attention beyond this
 CHUNK_Q = 1024
 
 
-def _gqa_attend(q, k, v, mask):
-    """q: (B,S,H,hd) k/v: (B,L,KV,hd) mask: (B,S,L) or (S,L) broadcastable."""
+def _gqa_attend(q, k, v, mask, scale=None):
+    """q: (B,S,H,hd) k/v: (B,L,KV,hd) mask: (B,S,L) or (S,L) broadcastable;
+    ``scale`` multiplies the scores (None: 1/sqrt(hd))."""
     B, S, H, hd = q.shape
     KV = k.shape[2]
     G = H // KV
     q = q.reshape(B, S, KV, G, hd)
     scores = jnp.einsum("bskgh,blkh->bkgsl", q, k,
                         preferred_element_type=jnp.float32)
-    scores = scores / jnp.sqrt(hd).astype(jnp.float32)
+    if scale is None:
+        scores = scores / jnp.sqrt(hd).astype(jnp.float32)
+    else:
+        scores = scores * jnp.float32(scale)
     scores = jnp.where(mask[:, None, None, :, :] if mask.ndim == 3
                        else mask[None, None, None, :, :], scores, NEG_INF)
     att = jax.nn.softmax(scores, axis=-1)
@@ -91,7 +98,7 @@ def _gqa_attend(q, k, v, mask):
     return out.reshape(B, S, H * hd_v).astype(v.dtype)
 
 
-def attend_seq_xla(q, k, v, *, causal: bool, q_offset=None):
+def attend_seq_xla(q, k, v, *, causal: bool, q_offset=None, scale=None):
     """The einsum/scan attention reference — ``Backend.attention``'s
     fallback path (short sequences, xla execution, active meshes).
 
@@ -100,7 +107,8 @@ def attend_seq_xla(q, k, v, *, causal: bool, q_offset=None):
     makes the 32k-prefill cells fit in HBM; the Pallas flash kernel is the
     TPU-native realization of the same schedule).  ``q_offset`` (python int
     or traced scalar) places query row i at absolute position q_offset + i
-    in the causal mask, keys at 0..L-1 — the chunked-prefill mask."""
+    in the causal mask, keys at 0..L-1 — the chunked-prefill mask.
+    ``scale`` multiplies the scores (None: 1/sqrt(hd))."""
     B, S, H, hd = q.shape
     L = k.shape[1]
     off = 0 if q_offset is None else q_offset
@@ -109,7 +117,7 @@ def attend_seq_xla(q, k, v, *, causal: bool, q_offset=None):
             mask = (off + jnp.arange(S))[:, None] >= jnp.arange(L)[None, :]
         else:
             mask = jnp.ones((S, L), dtype=bool)
-        return _gqa_attend(q, k, v, mask)
+        return _gqa_attend(q, k, v, mask, scale)
     nq = S // CHUNK_Q
     hd_v = v.shape[-1]
     qs = q.reshape(B, nq, CHUNK_Q, H, hd).transpose(1, 0, 2, 3, 4)
@@ -121,17 +129,19 @@ def attend_seq_xla(q, k, v, *, causal: bool, q_offset=None):
             mask = q_pos[:, None] >= jnp.arange(L)[None, :]
         else:
             mask = jnp.ones((CHUNK_Q, L), dtype=bool)
-        return None, _gqa_attend(qc, k, v, mask)
+        return None, _gqa_attend(qc, k, v, mask, scale)
 
     _, outs = jax.lax.scan(body, None, (qs, jnp.arange(nq)))
     return outs.transpose(1, 0, 2, 3).reshape(B, S, H * hd_v)
 
 
-def _attend_seq(q, k, v, causal: bool, backend=None, q_offset=None):
+def _attend_seq(q, k, v, causal: bool, backend=None, q_offset=None,
+                scale=None):
     """Full-sequence attention through the backend seam: the Backend
-    decides flash kernel vs einsum/scan (``Backend.attention``)."""
+    decides flash kernel vs einsum/scan (``Backend.attention``).
+    ``scale`` multiplies the scores (None: 1/sqrt(head dim))."""
     return resolve_backend(backend).attention(q, k, v, causal=causal,
-                                              q_offset=q_offset)
+                                              q_offset=q_offset, scale=scale)
 
 
 def gqa_forward(p, cfg: ModelConfig, x, *, transpose=False, causal=True,
@@ -299,17 +309,42 @@ def init_mla(key, cfg: ModelConfig):
     ks = jax.random.split(key, 4)
     p = {"wq": _dense_init(ks[0], (d, H * qd)),
          "w_dkv": _dense_init(ks[1], (d, m.kv_lora_rank + m.qk_rope_dim)),
+         "kv_norm": {"scale": jnp.ones((m.kv_lora_rank,))},
          "w_ukv": _dense_init(ks[2],
                               (m.kv_lora_rank, H * (m.qk_nope_dim
                                                     + m.v_head_dim))),
          "wo": _dense_init(ks[3], (H * m.v_head_dim, d))}
     s = {"wq": ("embed", "heads"), "w_dkv": ("embed", "kv_lora"),
+         "kv_norm": {"scale": ("kv_lora",)},
          "w_ukv": ("kv_lora", "heads"), "wo": ("heads", "embed")}
     return p, s
 
 
+def mla_softmax_scale(cfg: ModelConfig):
+    """YaRN's ``mscale(factor, mscale_all_dim)^2 / sqrt(qk_nope + qk_rope)``
+    under a scaled rope (DeepSeek-V2: about 1.59 over sqrt(192)); None
+    where it is the plain 1/sqrt(qk_nope + qk_rope)."""
+    rs = cfg.rope_scaling
+    if rs is None or not rs.mscale_all_dim:
+        return None
+    m = cfg.mla
+    return (yarn_mscale(rs.factor, rs.mscale_all_dim) ** 2
+            / math.sqrt(m.qk_nope_dim + m.qk_rope_dim))
+
+
+def _up_project(ckv, w_ukv):
+    """Latents (B, L, kv_lora) through ``w_ukv`` (kv_lora, H*(nope+v)).
+    ``w_ukv`` is never programmed into a bank: prefill up-projects and
+    decode absorbs it in the compute dtype alike, accumulating in float32
+    (``core/prepared.py``)."""
+    return jnp.einsum("blr,rn->bln", ckv, w_ukv.astype(ckv.dtype),
+                      preferred_element_type=jnp.float32).astype(ckv.dtype)
+
+
 def _mla_qkr(p, cfg, x, positions, backend=None):
-    """Project q (+rope) and the compressed kv latents for new tokens."""
+    """Project q (+rope) and the compressed kv latents for new tokens; the
+    latents pass the latent RMSNorm before anything caches or reads
+    them."""
     bk = resolve_backend(backend)
     m = cfg.mla
     B, S, _ = x.shape
@@ -319,7 +354,9 @@ def _mla_qkr(p, cfg, x, positions, backend=None):
     qn, qr = q[..., :m.qk_nope_dim], q[..., m.qk_nope_dim:]
     dkv = bk.dot(x, p["w_dkv"].astype(x.dtype), transpose=False)
     ckv, kr = dkv[..., :m.kv_lora_rank], dkv[..., m.kv_lora_rank:]
-    cos, sin = rope_angles(positions, m.qk_rope_dim, cfg.rope_theta)
+    ckv = apply_norm(p["kv_norm"], ckv, "rms", cfg.norm_eps)
+    cos, sin = rope_angles(positions, m.qk_rope_dim, cfg.rope_theta,
+                           cfg.rope_scaling)
     qr = apply_rope(qr, cos, sin)
     kr = apply_rope(kr[:, :, None, :], cos, sin)[:, :, 0, :]  # shared head
     return qn, qr, ckv, kr
@@ -334,14 +371,15 @@ def mla_forward(p, cfg: ModelConfig, x, *, transpose=False, causal=True,
     if positions is None:
         positions = jnp.arange(S)
     qn, qr, ckv, kr = _mla_qkr(p, cfg, x, positions, backend)
-    ukv = bk.dot(ckv, p["w_ukv"].astype(x.dtype), transpose=False)
+    ukv = _up_project(ckv, p["w_ukv"])
     ukv = ukv.reshape(B, S, H, m.qk_nope_dim + m.v_head_dim)
     kn, v = ukv[..., :m.qk_nope_dim], ukv[..., m.qk_nope_dim:]
     k = jnp.concatenate([kn, jnp.broadcast_to(kr[:, :, None, :],
                                               (B, S, H, m.qk_rope_dim))],
                         axis=-1)
     q = jnp.concatenate([qn, qr], axis=-1)
-    out = _attend_seq(q, k, v, causal, backend)     # KV == H here
+    out = _attend_seq(q, k, v, causal, backend,     # KV == H here
+                      scale=mla_softmax_scale(cfg))
     y = bk.dot(out, p["wo"].astype(x.dtype), transpose=False,
                tp_hint="row")
     if cache is not None:
@@ -373,15 +411,16 @@ def mla_prefill_chunk(p, cfg: ModelConfig, x, cache, q_offset, *,
     ckr = jax.lax.dynamic_update_slice(
         cache["kr"], kr_new.astype(cache["kr"].dtype), (0, q_offset, 0))
     L = cc.shape[1]
-    ukv = bk.dot(cc.astype(x.dtype), p["w_ukv"].astype(x.dtype),
-                 transpose=False)
+    with jax.named_scope("mla.up_proj"):
+        ukv = _up_project(cc.astype(x.dtype), p["w_ukv"])
     ukv = ukv.reshape(B, L, H, m.qk_nope_dim + m.v_head_dim)
     kn, v = ukv[..., :m.qk_nope_dim], ukv[..., m.qk_nope_dim:]
     k = jnp.concatenate(
         [kn, jnp.broadcast_to(ckr.astype(x.dtype)[:, :, None, :],
                               (B, L, H, m.qk_rope_dim))], axis=-1)
     q = jnp.concatenate([qn, qr], axis=-1)
-    out = _attend_seq(q, k, v, True, backend, q_offset)
+    out = _attend_seq(q, k, v, True, backend, q_offset,
+                      scale=mla_softmax_scale(cfg))
     y = bk.dot(out, p["wo"].astype(x.dtype), transpose=False,
                tp_hint="row")
     return y, {"ckv": cc, "kr": ckr}
@@ -407,23 +446,29 @@ def mla_decode(p, cfg: ModelConfig, x, cache, pos, *, transpose=False,
         m.kv_lora_rank, H, m.qk_nope_dim + m.v_head_dim)
     w_uk = w_ukv[..., :m.qk_nope_dim]          # (lora, H, nope)
     w_uv = w_ukv[..., m.qk_nope_dim:]          # (lora, H, v)
-    q_lat = jnp.einsum("bshn,rhn->bshr", qn, w_uk)      # absorb W_uk into q
-    scale = 1.0 / jnp.sqrt(m.qk_nope_dim + m.qk_rope_dim).astype(jnp.float32)
-    s_c = (jnp.einsum("bshr,blr->bhsl", q_lat, ckv,
-                      preferred_element_type=jnp.float32)
-           + jnp.einsum("bshr,blr->bhsl", qr, kr,
-                        preferred_element_type=jnp.float32)) * scale
-    s_c = jnp.where(_past_valid(pos, L)[:, None, None, :], s_c, NEG_INF)
-    s_n = (jnp.einsum("bshr,blr->bhsl", q_lat, ckv_new.astype(x.dtype),
-                      preferred_element_type=jnp.float32)
-           + jnp.einsum("bshr,blr->bhsl", qr, kr_new.astype(x.dtype),
-                        preferred_element_type=jnp.float32)) * scale
-    att = jax.nn.softmax(jnp.concatenate([s_c, s_n], axis=-1), axis=-1)
-    ctx_lat = (jnp.einsum("bhsl,blr->bshr", att[..., :L].astype(x.dtype),
-                          ckv)
-               + jnp.einsum("bhsl,blr->bshr", att[..., L:].astype(x.dtype),
-                            ckv_new.astype(x.dtype)))
-    ctx = jnp.einsum("bshr,rhv->bshv", ctx_lat, w_uv)
+    with jax.named_scope("mla.absorb"):
+        q_lat = jnp.einsum("bshn,rhn->bshr", qn, w_uk)  # absorb W_uk into q
+    scale = mla_softmax_scale(cfg)
+    scale = (1.0 / jnp.sqrt(m.qk_nope_dim + m.qk_rope_dim).astype(jnp.float32)
+             if scale is None else jnp.float32(scale))
+    with jax.named_scope("mla.latent_attn"):
+        s_c = (jnp.einsum("bshr,blr->bhsl", q_lat, ckv,
+                          preferred_element_type=jnp.float32)
+               + jnp.einsum("bshr,blr->bhsl", qr, kr,
+                            preferred_element_type=jnp.float32)) * scale
+        s_c = jnp.where(_past_valid(pos, L)[:, None, None, :], s_c, NEG_INF)
+        s_n = (jnp.einsum("bshr,blr->bhsl", q_lat, ckv_new.astype(x.dtype),
+                          preferred_element_type=jnp.float32)
+               + jnp.einsum("bshr,blr->bhsl", qr, kr_new.astype(x.dtype),
+                            preferred_element_type=jnp.float32)) * scale
+        att = jax.nn.softmax(jnp.concatenate([s_c, s_n], axis=-1), axis=-1)
+        ctx_lat = (jnp.einsum("bhsl,blr->bshr", att[..., :L].astype(x.dtype),
+                              ckv)
+                   + jnp.einsum("bhsl,blr->bshr",
+                                att[..., L:].astype(x.dtype),
+                                ckv_new.astype(x.dtype)))
+    with jax.named_scope("mla.absorb"):
+        ctx = jnp.einsum("bshr,rhv->bshv", ctx_lat, w_uv)
     y = bk.dot(ctx.reshape(B, S, H * m.v_head_dim),
                p["wo"].astype(x.dtype), transpose=False, tp_hint="row")
     return y, {"ckv": ckv_new.astype(ckv.dtype),

@@ -17,8 +17,11 @@ dispatches on the leaf type, so the same layer code serves both.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.backend import resolve as resolve_backend
 
@@ -49,12 +52,52 @@ def apply_norm(p, x, kind: str = "rms", eps: float = 1e-5):
 
 
 # ------------------------------------------------------------------ RoPE
-def rope_angles(positions: jax.Array, head_dim: int, theta: float):
-    """cos/sin tables for ``positions`` (any shape) -> (..., head_dim/2)."""
+def yarn_mscale(scale: float, mscale: float) -> float:
+    """YaRN's attention temperature factor ``0.1 * m * ln(s) + 1``."""
+    if scale <= 1.0:
+        return 1.0
+    return 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_inv_freq(head_dim: int, theta: float, rs) -> np.ndarray:
+    """YaRN frequencies (``rs``: ``configs.base.RopeScaling``): dims whose
+    wavelength fits ``beta_fast`` rotations in the original context keep
+    the plain frequency, those under ``beta_slow`` rotations take it
+    divided by ``factor``, and a linear ramp blends the dims between."""
     half = head_dim // 2
-    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    extra = 1.0 / (theta ** (np.arange(half, dtype=np.float64) / half))
+    inter = extra / rs.factor
+
+    def dim_of(rotations):
+        return (head_dim * math.log(rs.original_max_position
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    lo = max(math.floor(dim_of(rs.beta_fast)), 0)
+    hi = min(math.ceil(dim_of(rs.beta_slow)), head_dim - 1)
+    if lo == hi:
+        hi += 0.001
+    ramp = np.clip((np.arange(half) - lo) / (hi - lo), 0.0, 1.0)
+    return (inter * ramp + extra * (1.0 - ramp)).astype(np.float32)
+
+
+def rope_angles(positions: jax.Array, head_dim: int, theta: float,
+                scaling=None):
+    """cos/sin tables for ``positions`` (any shape) -> (..., head_dim/2);
+    ``scaling`` (a ``RopeScaling``) applies YaRN's frequencies and its
+    cos/sin factor ``mscale(factor, mscale) / mscale(factor,
+    mscale_all_dim)``."""
+    half = head_dim // 2
+    if scaling is None:
+        freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32)
+                                 / half))
+    else:
+        freqs = jnp.asarray(yarn_inv_freq(head_dim, theta, scaling))
     ang = positions.astype(jnp.float32)[..., None] * freqs
-    return jnp.cos(ang), jnp.sin(ang)
+    if scaling is None:
+        return jnp.cos(ang), jnp.sin(ang)
+    m = (yarn_mscale(scaling.factor, scaling.mscale)
+         / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+    return jnp.cos(ang) * m, jnp.sin(ang) * m
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array):

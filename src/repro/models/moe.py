@@ -1,7 +1,10 @@
 """Mixture-of-Experts FFN with grouped capacity dispatch (GShard style).
 
 Tokens are split into fixed-size *groups* (``MoEConfig.group_tokens``); each
-group routes independently with capacity ``C = ceil(g/E * top_k * cf)``.
+group routes independently with capacity ``C = ceil(g/E * top_k * cf)`` in
+training.  Serving (prefill, chunked prefill, decode) routes *dropless*:
+every expert has capacity for every row of its group, so a request's output
+never depends on its batch-mates or on padding rows.
 Dense one-hot dispatch/combine einsums keep every shape static (required for
 SPMD lowering) while both FLOPs and peak memory stay linear in tokens —
 O(tokens * E * C_g) with C_g fixed by the group size, NOT by the global
@@ -56,19 +59,24 @@ def _capacity(g: int, mcfg: MoEConfig) -> int:
     return max(min(cap, g), mcfg.top_k)
 
 
-def route(p, xg, mcfg: MoEConfig):
+def route(p, xg, mcfg: MoEConfig, dropless: bool = False):
     """Per-group routing.  xg: (G, g, d).
 
-    Returns dispatch (G,g,E,C), combine (G,g,E,C), aux losses.  Tokens
-    beyond an expert's capacity are dropped (standard GShard semantics)."""
+    Returns dispatch (G,g,E,C), combine (G,g,E,C), aux: the losses and
+    ``topk`` (G,g,K), each row's experts.  Gates are the top-k softmax
+    probabilities, renormalised to sum to 1 unless ``norm_topk_prob`` is
+    off.  Tokens beyond an expert's capacity are dropped (standard GShard
+    semantics); ``dropless`` sets the capacity to the group's rows, so none
+    is."""
     G, g, d = xg.shape
     E, K = mcfg.num_experts, mcfg.top_k
-    C = _capacity(g, mcfg)
+    C = g if dropless else _capacity(g, mcfg)
     logits = jnp.einsum("ngd,de->nge", xg.astype(jnp.float32),
                         p["router"].astype(jnp.float32))
     probs = jax.nn.softmax(logits, axis=-1)
     gate_vals, gate_idx = jax.lax.top_k(probs, K)          # (G,g,K)
-    gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
+    if mcfg.norm_topk_prob:
+        gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
     sel = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32)   # (G,g,K,E)
     mask = jnp.max(sel, axis=2)                            # (G,g,E) in {0,1}
     pos_in_e = jnp.cumsum(mask, axis=1) - 1.0              # (G,g,E)
@@ -83,8 +91,39 @@ def route(p, xg, mcfg: MoEConfig):
     frac_tokens = jnp.mean(mask, axis=(0, 1))
     frac_probs = jnp.mean(probs, axis=(0, 1))
     aux = {"load_balance": E * jnp.sum(frac_tokens * frac_probs),
-           "dropped_frac": 1.0 - jnp.sum(keep) / (G * g * K)}
+           "dropped_frac": 1.0 - jnp.sum(keep) / (G * g * K),
+           "topk": gate_idx}
     return dispatch, combine, aux
+
+
+def num_moe_layers(cfg) -> int:
+    """MoE layers one forward pass runs through (logical layers)."""
+    from repro.models.transformer import build_segments
+    return sum(spec.num_groups * spec.ffn_kinds.count("moe")
+               for spec in build_segments(cfg))
+
+
+def route_record(cfg, rows: int) -> dict:
+    """An aux accumulator that also keeps every MoE layer's routing of
+    ``rows`` rows: ``route`` (MoE layers, rows, top_k) expert ids, filled
+    in layer order (``accumulate_aux``)."""
+    return {"load_balance": jnp.float32(0.0),
+            "route": jnp.zeros((num_moe_layers(cfg), rows, cfg.moe.top_k),
+                               jnp.int32),
+            "layer": jnp.int32(0)}
+
+
+def accumulate_aux(aux, moe_aux):
+    """Add one MoE layer's aux to the stack's accumulator: a scalar (the
+    load-balance loss) or a ``route_record``."""
+    if not isinstance(aux, dict):
+        return aux + moe_aux["load_balance"]
+    K = moe_aux["topk"].shape[-1]
+    idx = moe_aux["topk"].reshape(1, -1, K).astype(jnp.int32)
+    return {"load_balance": aux["load_balance"] + moe_aux["load_balance"],
+            "route": jax.lax.dynamic_update_slice(
+                aux["route"], idx, (aux["layer"], 0, 0)),
+            "layer": aux["layer"] + 1}
 
 
 def _expert_weights(p, mcfg: MoEConfig, dtype):
@@ -181,41 +220,50 @@ def _photonic_expert_ffn(bk, p, xe, mcfg: MoEConfig, dtype, transpose):
     return out.reshape(E, G, C, d).transpose(1, 0, 2, 3)
 
 
-def apply_moe(p, x, mcfg: MoEConfig, transpose: bool = False, backend=None):
-    """x: (B, S, d) -> (B, S, d) plus aux losses.
+def apply_moe(p, x, mcfg: MoEConfig, transpose: bool = False, backend=None,
+              dropless: bool = False):
+    """x: (B, S, d) -> (B, S, d) plus aux (``route``'s).
 
     Routing stays electronic/fp32 on every backend (the router is a tiny
     matmul and top-k wants full precision); only the expert FFN banks route
-    through the photonic kernels."""
+    through the photonic kernels.  ``dropless`` (every serving path) gives
+    each expert capacity for its whole routing group; training keeps the
+    GShard capacity.  The one-hot dispatch then computes every expert at
+    the group's rows."""
     bk = resolve_backend(backend)
     B, S, d = x.shape
     G, g = _group_shape(B * S, mcfg)
     xg = x.reshape(G, g, d)
-    dispatch, combine, aux = route(p, xg, mcfg)
-    xe = jnp.einsum("ngec,ngd->necd", dispatch.astype(x.dtype), xg)
-    blend_experts = bool(mcfg.num_basic_experts
-                         and mcfg.num_basic_experts < mcfg.num_experts)
-    if bk.is_photonic:
-        ye = _photonic_expert_ffn(bk, p, xe, mcfg, x.dtype, transpose)
-    else:
-        wg, wu, wd = _expert_weights(p, mcfg, x.dtype)
-        if transpose:
-            gate = jnp.einsum("necd,efd->necf", xe, wd)  # W_down.T as up-proj
-            up = jnp.einsum("necd,edf->necf", xe, wu)
-            h = jax.nn.silu(gate) * up
-            ye = jnp.einsum("necf,edf->necd", h, wg)     # W_gate.T as down-proj
-        else:
-            gate = jnp.einsum("necd,edf->necf", xe, wg)
-            if blend_experts:
-                perms = _expert_gate_perms(mcfg)            # (E, f) static
-                gate = jnp.take_along_axis(
-                    gate, perms[None, :, None, :], axis=-1)
-            up = jnp.einsum("necd,edf->necf", xe, wu)
-            h = jax.nn.silu(gate) * up
-            ye = jnp.einsum("necf,efd->necd", h, wd)
-    yg = jnp.einsum("ngec,necd->ngd", combine.astype(x.dtype), ye)
+    with jax.named_scope("moe.route"):
+        dispatch, combine, aux = route(p, xg, mcfg, dropless)
+    with jax.named_scope("moe.dispatch"):
+        xe = jnp.einsum("ngec,ngd->necd", dispatch.astype(x.dtype), xg)
+    with jax.named_scope("moe.experts"):
+        ye = _expert_ffn(bk, p, xe, mcfg, x.dtype, transpose)
+    with jax.named_scope("moe.combine"):
+        yg = jnp.einsum("ngec,necd->ngd", combine.astype(x.dtype), ye)
     y = yg.reshape(B, S, d)
     if "shared" in p:
-        y = y + apply_mlp(p["shared"], x, act="swiglu", transpose=transpose,
-                          backend=bk)
+        with jax.named_scope("moe.shared"):
+            y = y + apply_mlp(p["shared"], x, act="swiglu",
+                              transpose=transpose, backend=bk)
     return y, aux
+
+
+def _expert_ffn(bk, p, xe, mcfg: MoEConfig, dtype, transpose):
+    """Every expert's SwiGLU on its capacity buffer xe (G, E, C, d)."""
+    if bk.is_photonic:
+        return _photonic_expert_ffn(bk, p, xe, mcfg, dtype, transpose)
+    wg, wu, wd = _expert_weights(p, mcfg, dtype)
+    if transpose:
+        gate = jnp.einsum("necd,efd->necf", xe, wd)  # W_down.T as up-proj
+        up = jnp.einsum("necd,edf->necf", xe, wu)
+        h = jax.nn.silu(gate) * up
+        return jnp.einsum("necf,edf->necd", h, wg)   # W_gate.T as down-proj
+    gate = jnp.einsum("necd,edf->necf", xe, wg)
+    if mcfg.num_basic_experts and mcfg.num_basic_experts < mcfg.num_experts:
+        perms = _expert_gate_perms(mcfg)             # (E, f) static
+        gate = jnp.take_along_axis(gate, perms[None, :, None, :], axis=-1)
+    up = jnp.einsum("necd,edf->necf", xe, wu)
+    h = jax.nn.silu(gate) * up
+    return jnp.einsum("necf,efd->necd", h, wd)

@@ -228,9 +228,11 @@ def apply_layer(p, cfg: ModelConfig, h, cache, aux, *, mixer_kind, ffn_kind,
     if ffn_kind != "none":
         hn = apply_norm(p["norm2"], h, cfg.norm, cfg.norm_eps)
         if ffn_kind == "moe":
+            # every serving mode routes dropless (models/moe.py)
             y, moe_aux = moe_lib.apply_moe(p["ffn"], hn, cfg.moe,
-                                           transpose=transpose, backend=bk)
-            aux = aux + moe_aux["load_balance"]
+                                           transpose=transpose, backend=bk,
+                                           dropless=mode != "train")
+            aux = moe_lib.accumulate_aux(aux, moe_aux)
         else:
             y = apply_mlp(p["ffn"], hn, act=cfg.mlp_act, transpose=transpose,
                           backend=bk)
@@ -381,7 +383,7 @@ def _encoder_pass(params, cfg, batch, ctx, aux):
 
 def forward(params, cfg: ModelConfig, batch, *, mode="train", caches=None,
             pos=None, act_pspec=None, remat=False, legacy_decode=False,
-            execution=None):
+            execution=None, record_routing=False):
     """Run the model.
 
     batch: {"tokens": (B, S)} plus modality extras:
@@ -402,6 +404,9 @@ def forward(params, cfg: ModelConfig, batch, *, mode="train", caches=None,
       prepared ``core.prepared.PreparedTensor`` banks (write-once; the
       layers dispatch transparently).  New code should call this through
       :class:`repro.api.Program` rather than threading kwargs per call.
+    record_routing: on an MoE model, aux is ``moe.route_record``'s dict
+      instead of the load-balance scalar: its ``route`` holds every MoE
+      layer's expert ids of every row.
     Returns (logits, new_caches, aux).
     """
     dtype = jnp.dtype(cfg.compute_dtype)
@@ -411,6 +416,8 @@ def forward(params, cfg: ModelConfig, batch, *, mode="train", caches=None,
                            "remat": remat, "legacy_decode": legacy_decode,
                            "backend": backend}
     aux = jnp.float32(0.0)
+    if record_routing and cfg.moe is not None:
+        aux = moe_lib.route_record(cfg, batch["tokens"].size)
     segs = build_segments(cfg)
     shareds = _shareds_for(cfg)
     # ---- modality memory streams ----

@@ -93,11 +93,21 @@ for _f in ("waves", "padded_tokens"):
 
 class ContinuousStats(ServingStats):
     """Continuous batching: bucket padding + idle decode lanes, plus the
-    per-step active-slot occupancy distribution."""
+    per-step active-slot occupancy distribution.
 
+    On a model with experts, every decode step also counts, over its live
+    slots and summed over its MoE layers: ``moe_routed_tokens`` (row-expert
+    assignments, live rows x top_k), ``moe_experts_hit`` (experts that took
+    at least one live row: the expert banks a step needs to read) and
+    ``moe_max_expert_load`` (the most live rows any one expert took), so a
+    window's deltas over ``decode_steps`` give their means."""
+
+    MOE_FIELDS = ("moe_routed_tokens", "moe_experts_hit",
+                  "moe_max_expert_load")
     FIELDS = ServingStats.FIELDS + ("prefills", "decode_steps",
                                     "padded_prefill_tokens",
-                                    "idle_slot_steps", "prefill_chunks")
+                                    "idle_slot_steps",
+                                    "prefill_chunks") + MOE_FIELDS
 
     def __init__(self, registry: _metrics.MetricsRegistry | None = None,
                  _capacity: int = 1):
@@ -108,6 +118,13 @@ class ContinuousStats(ServingStats):
         self.occupancy: collections.Counter = collections.Counter()
         self._occ_hist = self.registry.histogram("serve.active_slots",
                                                  lo=0.5, growth=1.05)
+
+    def observe_routing(self, loads) -> None:
+        """Count one decode step's routing: ``loads`` (MoE layers, experts)
+        live rows per expert."""
+        self.moe_routed_tokens += int(loads.sum())
+        self.moe_experts_hit += int((loads > 0).sum())
+        self.moe_max_expert_load += int(loads.max(axis=1).sum())
 
     def observe_active(self, n: int) -> None:
         """Record one decode step's active-slot count."""
@@ -135,6 +152,6 @@ class ContinuousStats(ServingStats):
 
 
 for _f in ("prefills", "decode_steps", "padded_prefill_tokens",
-           "idle_slot_steps", "prefill_chunks"):
+           "idle_slot_steps", "prefill_chunks") + ContinuousStats.MOE_FIELDS:
     setattr(ContinuousStats, _f, _counter_property(_f))
 del _f

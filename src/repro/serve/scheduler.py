@@ -111,6 +111,13 @@ class ReuseAwareAdmission:
         return min(queued, free, self.max_admit_per_step)
 
 
+def expert_loads(route: np.ndarray, live, num_experts: int) -> np.ndarray:
+    """(MoE layers, experts) rows each expert took, over the ``live`` rows
+    of a decode step's expert ids ``route`` (MoE layers, rows, top_k)."""
+    ids = route[:, live, :].reshape(route.shape[0], -1)
+    return (ids[..., None] == np.arange(num_experts)).sum(axis=1)
+
+
 # =========================================================================
 # continuous scheduler
 # =========================================================================
@@ -493,12 +500,18 @@ class ContinuousScheduler:
                 self.residency.on_decode_step(self.pool.capacity)
             if self.calibration is not None:
                 self.calibration.on_step()
-            nxt, self.pool.caches = self.program.decode_sample(
+            nxt, self.pool.caches, route = self.program.decode_sample(
                 jnp.asarray(self._cur), self.pool.caches,
                 self.pool.position_vector(), key=self._next_key(),
-                temperature=self.temperature)
+                temperature=self.temperature, routing=True)
         with span("sched.decode.wait", tracer=tr):
             nxt = np.asarray(nxt)
+            if route is not None:
+                # an MoE step's expert ids, ready with the tokens
+                route = np.asarray(route)
+        if route is not None:
+            self.stats.observe_routing(
+                expert_loads(route, active, self.cfg.moe.num_experts))
         self.stats.decode_steps += 1
         self.stats.slot_steps += self.pool.capacity
         self.stats.idle_slot_steps += self.pool.capacity - len(active)

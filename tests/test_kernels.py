@@ -153,6 +153,29 @@ def test_flash_attention_vs_ref(S, hd, causal):
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("causal,q_offset", [(True, None), (True, 64),
+                                              (False, None)])
+def test_flash_attention_scale_matches_attend_seq_xla(causal, q_offset):
+    """A non-default softmax scale (MLA's mscale^2 / sqrt(hd)), with MLA's
+    v head dim, on the kernel and on the einsum path alike."""
+    from repro.models.attention import attend_seq_xla
+    B, S, H, hd, hdv = 2, 64, 3, 24, 16
+    L = S + (q_offset or 0)
+    scale = 1.5896 / hd ** 0.5
+    ks = jax.random.split(jax.random.PRNGKey(7), 3)
+    q = jax.random.normal(ks[0], (B, S, H, hd))
+    k = jax.random.normal(ks[1], (B, L, H, hd))
+    v = jax.random.normal(ks[2], (B, L, H, hdv))
+    got = ops.flash_attention(q, k, v, causal=causal, q_offset=q_offset,
+                              bq=32, bk=32, scale=scale)
+    want = attend_seq_xla(q, k, v, causal=causal, q_offset=q_offset,
+                          scale=scale)
+    np.testing.assert_allclose(np.asarray(got).reshape(B, S, H * hdv),
+                               np.asarray(want), rtol=2e-5, atol=2e-5)
+    plain = attend_seq_xla(q, k, v, causal=causal, q_offset=q_offset)
+    assert np.abs(np.asarray(plain) - np.asarray(want)).max() > 1e-2
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_attention_dtypes(dtype):
     B, S, H, hd = 1, 64, 2, 16
